@@ -99,26 +99,23 @@ def main(n_points: int = 50_000, n_queries: int = 200,
     # protocol: the per-shard traversal + cross-shard merge, end to end
     if n_shards > 1:
         import time as _time
-        import jax
         import jax.numpy as jnp
         from benchmarks.common import make_bench_filter
         from repro.core.distributed import (build_sharded,
                                             distributed_search,
-                                            shard_search_host)
+                                            serving_mesh)
         from repro.core.search_ref import recall_at
+        # one device per shard, or fail: serving_mesh refuses to fall
+        # back to the single-device shard loop
+        mesh = serving_mesh(n_shards)
         filt = make_bench_filter(filter_kind, cfg, x, pca,
                                  levels=g.levels)
-        sdb = build_sharded(x, cfg, filt, n_shards)
+        sdb = build_sharded(x, cfg, filt, n_shards, mesh=mesh)
         qd = jnp.asarray(q[:B])
         qprep = filt.prepare_jnp(qd)
-        on_mesh = len(jax.devices()) >= n_shards
         kw = dict(deferred=deferred,
                   rerank_mult=int(rerank_mult or cfg.rerank_mult))
-        if on_mesh:
-            mesh = jax.make_mesh((1, n_shards), ("data", "model"))
-            run = lambda: distributed_search(mesh, sdb, qd, qprep, **kw)
-        else:
-            run = lambda: shard_search_host(sdb, qd, qprep, **kw)
+        run = lambda: distributed_search(mesh, sdb, qd, qprep, **kw)
         run()[1].block_until_ready()                   # compile
         t0 = _time.perf_counter()
         reps = 5
@@ -133,7 +130,7 @@ def main(n_points: int = 50_000, n_queries: int = 200,
         rows.append((f"table3/pHNSW-JAX-sharded/p{n_shards}-{mode}",
                      dt / B * 1e6,
                      f"qps={B / dt:.0f};recall@10={rec:.3f};"
-                     f"path={'mesh' if on_mesh else 'host'};"
+                     f"path=mesh;platform={mesh.devices.flat[0].platform};"
                      f"vs_1shard={m['qps'] / (B / dt):.2f}x_slowdown"))
 
     # the tracked perf trajectory pins the canonical single-shard

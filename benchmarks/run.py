@@ -70,18 +70,25 @@ def main() -> None:
     ap.add_argument("--shards", type=int, default=1,
                     help="shard the database P ways and measure the "
                          "distributed path (perf-smoke and churn "
-                         "benches); forces P simulated host devices so "
-                         "the mesh collective path runs, and never "
-                         "touches the tracked BENCH_table3.json entry")
+                         "benches) on a mesh of P devices; on the CPU "
+                         "(JAX_PLATFORMS=cpu) P host devices are "
+                         "simulated. Never touches the tracked "
+                         "BENCH_table3.json entry")
     args = ap.parse_args()
-    if args.shards > 1:
-        # must precede the first jax import anywhere below
-        import os
+    import os
+    if args.shards > 1 and os.environ.get("JAX_PLATFORMS") == "cpu":
+        # simulated host devices; must precede the first jax use
         flags = os.environ.get("XLA_FLAGS", "")
         if "host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{args.shards}").strip()
+    import jax
+    from repro.runtime import enable_compile_cache
+    dev = jax.devices()[0]
+    print(f"# device {dev.platform} {dev.device_kind} x"
+          f"{len(jax.devices())}; compile cache {enable_compile_cache()}",
+          file=sys.stderr)
     n_points = args.n_points or \
         (8_000 if args.fast or args.perf_smoke else 50_000)
     n_queries = 64 if args.fast or args.perf_smoke else 200
@@ -186,21 +193,15 @@ def main() -> None:
             print(f"# {mod.__name__} FAILED", file=sys.stderr)
             traceback.print_exc()
             raise
-    # roofline appendix (if the dry-run has been run)
-    try:
-        from repro.launch.roofline import load_all
-        rows = load_all("pod16x16")
-        if rows:
-            for r in rows:
-                step_s = max(r["compute_s"], r["memory_s"],
-                             r["collective_s"])
-                print(f"roofline/{r['arch']}/{r['shape']},"
-                      f"{step_s * 1e6:.1f},"
-                      f"bottleneck={r['bottleneck']};"
-                      f"roofline_frac={r['roofline_fraction']};"
-                      f"useful_flops={r['useful_flops_ratio']}")
-    except Exception:
-        pass
+    # roofline appendix (rows only if the dry-run has been run)
+    from repro.launch.roofline import load_all
+    for r in load_all("pod16x16"):
+        step_s = max(r["compute_s"], r["memory_s"], r["collective_s"])
+        print(f"roofline/{r['arch']}/{r['shape']},"
+              f"{step_s * 1e6:.1f},"
+              f"bottleneck={r['bottleneck']};"
+              f"roofline_frac={r['roofline_fraction']};"
+              f"useful_flops={r['useful_flops_ratio']}")
     print(f"# total {time.time() - t0:.1f}s", file=sys.stderr)
 
 

@@ -37,7 +37,9 @@ through the same probe + ``link_wave`` pipeline.
 """
 from __future__ import annotations
 
+import os
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional
 
 import numpy as np
@@ -143,7 +145,10 @@ def link_wave_layer(x: np.ndarray, adj_l: np.ndarray,
         return np.empty(0, np.int64)
 
     # --- forward: each wave node's own neighbor row ---
-    rows, total, sel = select_heuristic_batch(x, cand_d, cand_i, m)
+    rows, total, sel = (np.concatenate(r) for r in zip(*thread_map(
+        lambda lo: select_heuristic_batch(x, cand_d[lo:lo + _ROWS],
+                                          cand_i[lo:lo + _ROWS], m),
+        range(0, len(cand_d), _ROWS))))
     has = total > 0
     adj_l[node_ids[has]] = rows[has]
 
@@ -175,31 +180,65 @@ def link_wave_layer(x: np.ndarray, adj_l: np.ndarray,
         adj_l[t_s[app], (first_free[inv] + within)[app]] = s_s[app]
 
     # overfull targets: re-select {existing row + all incoming} with the
-    # batched diversity heuristic
+    # batched diversity heuristic, in chunks of targets ordered by
+    # incoming count. A hub that draws many links in one wave then pads
+    # only its own chunk to its width (the padding is inert: -1/INF
+    # candidates are never selected), each chunk's [rows, m + R, D]
+    # candidate block stays small, and chunks (disjoint rows) run on
+    # threads.
     if overfull.any():
         uo = ut[overfull]                        # [U]
-        o_of = np.cumsum(overfull) - 1           # ut idx -> uo idx
+        cnt_o = cnt[overfull]
         pm = overfull[inv]                       # pairs on overfull tgts
-        R = int(cnt[overfull].max())
-        U = len(uo)
-        inc_i = np.full((U, R), -1, np.int64)
-        inc_d = np.full((U, R), INF, np.float32)
-        inc_i[o_of[inv[pm]], within[pm]] = s_s[pm]
-        inc_d[o_of[inv[pm]], within[pm]] = d_s[pm]
-        ex_i = adj_l[uo].astype(np.int64)        # [U, m]
-        ex_ok = ex_i >= 0
-        diff = x[np.where(ex_ok, ex_i, 0)] - x[uo][:, None, :]
-        ex_d = np.einsum("umd,umd->um", diff, diff).astype(np.float32)
-        ex_d = np.where(ex_ok, ex_d, INF)
-        c2_d = np.concatenate([ex_d, inc_d], 1)
-        c2_i = np.concatenate([ex_i, inc_i], 1)
-        o2 = np.argsort(c2_d, axis=1, kind="stable")
-        c2_d = np.take_along_axis(c2_d, o2, 1)
-        c2_i = np.take_along_axis(c2_i, o2, 1)
-        rows2, _, _ = select_heuristic_batch(x, c2_d, c2_i, m)
-        adj_l[uo] = rows2
+        p_tgt = (np.cumsum(overfull) - 1)[inv[pm]]   # pair -> uo idx
+        p_col, p_src, p_d = within[pm], s_s[pm], d_s[pm]
+        by_cnt = np.argsort(cnt_o, kind="stable")
+        rank = np.empty_like(by_cnt)
+        rank[by_cnt] = np.arange(len(by_cnt))
+        p_rank = rank[p_tgt]
+
+        def reselect(lo):
+            part = by_cnt[lo:lo + _ROWS]
+            tgt = uo[part]
+            R = int(cnt_o[part].max())
+            mine = (p_rank >= lo) & (p_rank < lo + len(part))
+            r_loc = p_rank[mine] - lo
+            inc_i = np.full((len(part), R), -1, np.int64)
+            inc_d = np.full((len(part), R), INF, np.float32)
+            inc_i[r_loc, p_col[mine]] = p_src[mine]
+            inc_d[r_loc, p_col[mine]] = p_d[mine]
+            ex_i = adj_l[tgt].astype(np.int64)   # [rows, m]
+            ex_ok = ex_i >= 0
+            diff = x[np.where(ex_ok, ex_i, 0)] - x[tgt][:, None, :]
+            ex_d = np.einsum("umd,umd->um", diff, diff).astype(np.float32)
+            ex_d = np.where(ex_ok, ex_d, INF)
+            c2_d = np.concatenate([ex_d, inc_d], 1)
+            c2_i = np.concatenate([ex_i, inc_i], 1)
+            o2 = np.argsort(c2_d, axis=1, kind="stable")
+            c2_d = np.take_along_axis(c2_d, o2, 1)
+            c2_i = np.take_along_axis(c2_i, o2, 1)
+            rows2, _, _ = select_heuristic_batch(x, c2_d, c2_i, m)
+            return tgt, rows2
+
+        for tgt, rows2 in thread_map(reselect, range(0, len(uo), _ROWS)):
+            adj_l[tgt] = rows2
 
     return np.unique(np.concatenate([node_ids[has], ut]))
+
+
+# rows per chunk of the batched heuristic in ``link_wave_layer``
+_ROWS = 1024
+
+
+def thread_map(fn, items) -> list:
+    """``fn`` over ``items`` on a few threads, results in item order:
+    the linking is numpy on arrays large enough that it runs outside
+    the interpreter lock, so threads use the host's cores."""
+    items = list(items)
+    if len(items) <= 1:
+        return [fn(i) for i in items]
+    with ThreadPoolExecutor(min(len(items), os.cpu_count() or 1, 8)) as ex:
+        return list(ex.map(fn, items))
 
 
 def link_wave(x: np.ndarray, adj: List[np.ndarray],

@@ -50,24 +50,25 @@ mesh == host.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import List, Optional, Tuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import PHNSWConfig
 from repro.constants import INF as _INF
 from repro.core.graph import build_hnsw
 from repro.core.pca import PCA
-from repro.core.search_jax import (PackedDB, PackedLayer, build_packed,
-                                   pack_bitmap, _rank_sort_with_payload,
+from repro.core.search_jax import (PackedDB, PackedLayer, pack_bitmap,
+                                   pack_host, _rank_sort_with_payload,
                                    _search_batched_impl)
 from repro.kernels import ops
 
@@ -178,10 +179,66 @@ def _pad_rows(a: np.ndarray, n: int, fill) -> np.ndarray:
     return np.concatenate([a, pad])
 
 
+def serving_mesh(n_shards: int) -> Mesh:
+    """The (1, P) ``("data", "model")`` mesh a P-shard index is served
+    on: shard s on device s. Fails, rather than falling back to one
+    device, when there are fewer devices than shards."""
+    devs = jax.devices()
+    if len(devs) < n_shards:
+        raise ValueError(f"{n_shards} shards need {n_shards} devices; "
+                         f"found {len(devs)} ({devs[0].platform})")
+    auto = jax.sharding.AxisType.Auto
+    return jax.make_mesh((1, n_shards), ("data", "model"), (auto, auto),
+                         devices=devs[:n_shards])
+
+
+def place_stacked(parts, mesh: Optional[Mesh] = None) -> jax.Array:
+    """Stack per-shard arrays along a new leading shard dim. With a
+    ``mesh``, part s goes straight to the device at ``model`` index s
+    (``NamedSharding(mesh, P("model"))``), never staged on one device;
+    without one, the stack lives on the default device."""
+    if mesh is None:
+        return jnp.stack(parts)
+    shape = (len(parts),) + tuple(np.shape(parts[0]))
+    sharding = NamedSharding(mesh, P("model"))
+    rows = []
+    for dev, idx in sharding.addressable_devices_indices_map(shape).items():
+        part = parts[idx[0].start]
+        part = part[None] if isinstance(part, jax.Array) \
+            else np.asarray(part)[None]
+        rows.append(jax.device_put(part, dev))
+    return jax.make_array_from_single_device_arrays(shape, sharding, rows)
+
+
+def build_shard_graphs(x: np.ndarray, cfg: PHNSWConfig, n_shards: int,
+                       *, seed: int = 0, builder: Optional[str] = None,
+                       mesh: Optional[Mesh] = None):
+    """One HNSW graph per ``shard_bounds`` partition, shard s seeded
+    ``seed + s``. With a ``mesh`` every shard builds at once, shard s on
+    the device at ``model`` index s: each wave probe runs on its own
+    chip, and the host-side linking is numpy that mostly runs outside
+    the interpreter lock. The graphs are the same either way."""
+    bounds = shard_bounds(len(x), n_shards)
+    devs = None if mesh is None else list(mesh.devices.reshape(-1))
+
+    def one(s):
+        a, b = bounds[s]
+        on = contextlib.nullcontext() if devs is None \
+            else jax.default_device(devs[s])
+        with on:
+            return build_hnsw(x[a:b], cfg, seed=seed + s, builder=builder)
+
+    if devs is None:
+        return [one(s) for s in range(n_shards)]
+    with ThreadPoolExecutor(n_shards) as ex:
+        return list(ex.map(one, range(n_shards)))
+
+
 def build_sharded(x: np.ndarray, cfg: PHNSWConfig, filt, n_shards: int,
                   *, deleted: Optional[np.ndarray] = None,
                   graphs=None, seed: int = 0,
-                  builder: Optional[str] = None) -> ShardedDB:
+                  builder: Optional[str] = None,
+                  mesh: Optional[Mesh] = None) -> ShardedDB:
     """Partition ``x`` into ``n_shards`` (remainder distributed, no tail
     dropped), build one HNSW graph per shard, and stack the packed
     databases. ``filt`` is the SHARED filter — any
@@ -193,50 +250,50 @@ def build_sharded(x: np.ndarray, cfg: PHNSWConfig, filt, n_shards: int,
     callers comparing filter kinds build once. Shard builds route
     through the one construction pipeline (``builder`` defaults to
     ``cfg.builder`` — the wave pipeline; equal-sized shards share its
-    compiled probe program, so P shards pay ONE compile)."""
+    compiled probe program, so P shards pay ONE compile). ``mesh`` (a
+    ``serving_mesh``) builds the shards concurrently, each on its own
+    device (``build_shard_graphs``), and places each shard's arrays
+    there; without one every leaf lives on the default device."""
     from repro.core.filters import PCAFilter
     if isinstance(filt, PCA):
         filt = PCAFilter(filt, low_dtype=cfg.low_dtype)
     n = len(x)
     bounds = shard_bounds(n, n_shards)
     n_max = max(e - s for s, e in bounds)
-    dbs, offs, cnts, dels = [], [], [], []
+    if graphs is None:
+        graphs = build_shard_graphs(x, cfg, n_shards, seed=seed,
+                                    builder=builder, mesh=mesh)
+    hosts, highs, entries, dels = [], [], [], []
     for s, (a, b) in enumerate(bounds):
-        xs = x[a:b]
-        if graphs is not None:
-            g = graphs[s]
-            assert len(g.x) == b - a, "graphs must match shard_bounds"
-        else:
-            g = build_hnsw(xs, cfg, seed=seed + s, builder=builder)
+        xs, g = x[a:b], graphs[s]
+        assert len(g.x) == b - a, "graphs must match shard_bounds"
         # keep layer counts uniform across shards for stacking
-        dbs.append(build_packed(g, filt.encode(xs), filt=filt,
-                                drop_empty_layers=False))
-        offs.append(a)
-        cnts.append(b - a)
+        hosts.append(pack_host(g, filt.encode(xs), filt=filt,
+                               drop_empty_layers=False))
+        highs.append(_pad_rows(np.asarray(xs, np.float32), n_max, 0))
+        entries.append(np.int32(g.entry))
         if deleted is not None:
             # pad slots marked deleted too (unreachable, but the bitmap
             # shape must stack)
             d = _pad_rows(deleted[a:b].astype(bool), n_max, True)
             dels.append(pack_bitmap(d))
-    stack = lambda xs: jnp.stack(xs)
-    n_layers = len(dbs[0].layers)
+    stack = lambda xs: place_stacked(xs, mesh)
+    pad = lambda arrs, fill: stack([_pad_rows(a, n_max, fill)
+                                    for a in arrs])
+    n_layers = len(hosts[0].adj)
     return ShardedDB(
-        adj=[stack([_pad_rows(np.asarray(db.layers[l].adj), n_max, -1)
-                    for db in dbs]) for l in range(n_layers)],
-        packed_low=[stack([_pad_rows(np.asarray(db.layers[l].packed_low),
-                                     n_max, 0) for db in dbs])
+        adj=[pad([h.adj[l] for h in hosts], -1) for l in range(n_layers)],
+        packed_low=[pad([h.packed_low[l] for h in hosts], 0)
                     for l in range(n_layers)],
-        low=stack([_pad_rows(np.asarray(db.low), n_max, 0)
-                   for db in dbs]),
-        high=stack([_pad_rows(np.asarray(db.high), n_max, 0)
-                    for db in dbs]),
-        entries=jnp.asarray([db.entry for db in dbs], jnp.int32),
-        offsets=jnp.asarray(offs, jnp.int32),
-        counts=jnp.asarray(cnts, jnp.int32),
+        low=pad([h.low for h in hosts], 0),
+        high=stack(highs),
+        entries=stack(entries),
+        offsets=stack([np.int32(a) for a, _ in bounds]),
+        counts=stack([np.int32(b - a) for a, b in bounds]),
         cfg=cfg,
         deleted=None if deleted is None else stack(dels),
-        low2=None if dbs[0].low2 is None else
-        stack([_pad_rows(np.asarray(db.low2), n_max, 0) for db in dbs]),
+        low2=None if hosts[0].low2 is None else
+        pad([h.low2 for h in hosts], 0),
         filter_kind=filt.kind,
     )
 
@@ -403,8 +460,12 @@ def _mesh_search_jit(mesh, sdb, queries, qprep, live, ef0, k_schedule,
         q_spec, qp_spec,
     )
     out_specs = (P(b_ax, None), P(b_ax, None))
-    fn = shard_map(local_search, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    # no varying-axis typing: the per-shard search seeds while_loop
+    # carries with shard-invariant constants that its body turns
+    # shard-varying; the out_specs hold by construction (all_gather /
+    # psum outputs)
+    fn = jax.shard_map(local_search, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     dele = sdb.deleted if has_del else jnp.zeros((), jnp.int32)
     lo2 = sdb.low2 if cascade else jnp.zeros((), jnp.float32)
     return fn(sdb.adj, sdb.packed_low, sdb.low, sdb.high, sdb.entries,

@@ -16,6 +16,8 @@ from typing import Tuple
 
 import numpy as np
 
+from repro.core.build import thread_map
+
 
 @dataclass
 class PQCodebook:
@@ -53,6 +55,12 @@ def _init_centroids(xs: np.ndarray, rng: np.random.Generator,
     return c
 
 
+# rows per [rows, 256, dsub] distance block in training and encoding:
+# ~17 MB at dsub=8, small enough that the threads reuse heap memory
+# instead of mapping and unmapping a large temporary per block
+_BLOCK = 2048
+
+
 def train_pq(x: np.ndarray, n_sub: int, *, iters: int = 8,
              seed: int = 0, weights: np.ndarray = None) -> PQCodebook:
     """Lloyd k-means (k=256) per subspace.
@@ -74,22 +82,20 @@ def train_pq(x: np.ndarray, n_sub: int, *, iters: int = 8,
         assert w.shape == (n,) and (w >= 0).all() and w.sum() > 0, \
             "weights must be [n] non-negative with positive sum"
         p = w / w.sum()
-    cents = np.empty((n_sub, 256, dsub), np.float32)
-    for m in range(n_sub):
-        xs = x[:, m * dsub:(m + 1) * dsub].astype(np.float32)
-        c = _init_centroids(xs, rng, p)
+    # every random draw happens here, in subspace order; the Lloyd
+    # iterations below draw nothing, so the subspaces run on threads
+    sub = [x[:, m * dsub:(m + 1) * dsub].astype(np.float32)
+           for m in range(n_sub)]
+    init = [_init_centroids(xs, rng, p) for xs in sub]
+
+    def lloyd(m):
+        xs, c = sub[m], init[m]
         for _ in range(iters):
-            d2 = ((xs[:, None, :] - c[None]) ** 2).sum(-1) \
-                if n <= 20000 else None
-            if d2 is None:
-                # blockwise assignment for larger n
-                assign = np.empty(n, np.int64)
-                for i in range(0, n, 8192):
-                    blk = xs[i:i + 8192]
-                    d2b = ((blk[:, None, :] - c[None]) ** 2).sum(-1)
-                    assign[i:i + 8192] = d2b.argmin(1)
-            else:
-                assign = d2.argmin(1)
+            assign = np.empty(n, np.int64)
+            for i in range(0, n, _BLOCK):
+                blk = xs[i:i + _BLOCK]
+                d2 = ((blk[:, None, :] - c[None]) ** 2).sum(-1)
+                assign[i:i + _BLOCK] = d2.argmin(1)
             empty = []
             for k in range(256):
                 sel = assign == k
@@ -110,8 +116,10 @@ def train_pq(x: np.ndarray, n_sub: int, *, iters: int = 8,
                 far = np.argsort(-d_assigned)
                 for k, i in zip(empty, far):
                     c[k] = xs[i]
-        cents[m] = c
-    return PQCodebook(centroids=cents)
+        return c
+
+    return PQCodebook(centroids=np.stack(thread_map(lloyd,
+                                                     range(n_sub))))
 
 
 def encode_pq(cb: PQCodebook, x: np.ndarray) -> np.ndarray:
@@ -119,12 +127,15 @@ def encode_pq(cb: PQCodebook, x: np.ndarray) -> np.ndarray:
     n, d = x.shape
     dsub = cb.dsub
     codes = np.empty((n, cb.n_sub), np.uint8)
-    for m in range(cb.n_sub):
+
+    def encode(m):          # one subspace's column; subspaces on threads
         xs = x[:, m * dsub:(m + 1) * dsub].astype(np.float32)
-        for i in range(0, n, 8192):
-            blk = xs[i:i + 8192]
+        for i in range(0, n, _BLOCK):
+            blk = xs[i:i + _BLOCK]
             d2 = ((blk[:, None, :] - cb.centroids[m][None]) ** 2).sum(-1)
-            codes[i:i + 8192, m] = d2.argmin(1).astype(np.uint8)
+            codes[i:i + _BLOCK, m] = d2.argmin(1).astype(np.uint8)
+
+    thread_map(encode, range(cb.n_sub))
     return codes
 
 

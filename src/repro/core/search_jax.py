@@ -169,6 +169,32 @@ def build_packed(g: HNSWGraph, x_low: Optional[np.ndarray] = None,
     small N) so the search never runs a while_loop over an empty graph
     layer; pass False when layer counts must stay uniform (e.g.
     stacking shards)."""
+    h = pack_host(g, x_low, filt=filt, low_dtype=low_dtype,
+                  drop_empty_layers=drop_empty_layers)
+    layers = [PackedLayer(adj=jnp.asarray(a), packed_low=jnp.asarray(p))
+              for a, p in zip(h.adj, h.packed_low)]
+    return PackedDB(layers=layers, low=jnp.asarray(h.low),
+                    high=jnp.asarray(g.x), entry=g.entry, cfg=g.cfg,
+                    low2=None if h.low2 is None else jnp.asarray(h.low2),
+                    filter_kind=h.filter_kind)
+
+
+@dataclass
+class HostPacked:
+    """``build_packed``'s arrays before upload (numpy, in device dtype):
+    the sharded builder stacks these and places each shard on its own
+    device without staging whole shards on the default one."""
+    adj: List[np.ndarray]          # per layer [N, M_l] int32
+    packed_low: List[np.ndarray]   # per layer [N, M_l, P]
+    low: np.ndarray                # [N, P]
+    low2: Optional[np.ndarray]     # [N, d_low] cascade side-car or None
+    filter_kind: str
+
+
+def pack_host(g: HNSWGraph, x_low: Optional[np.ndarray] = None, *,
+              filt=None, low_dtype: Optional[str] = None,
+              drop_empty_layers: bool = True) -> HostPacked:
+    """The host half of ``build_packed`` (same arguments)."""
     fkind = filt.kind if filt is not None else "pca"
     if x_low is None:
         if filt is None:
@@ -180,20 +206,20 @@ def build_packed(g: HNSWGraph, x_low: Optional[np.ndarray] = None,
     if drop_empty_layers:
         while len(adjs) > 1 and not (adjs[-1] >= 0).any():
             adjs.pop()
-    layers = []
+    packed_low = []
     for adj in adjs:
         safe = np.where(adj >= 0, adj, 0)
         packed = x_low[safe]                       # [N, M, P]
         packed[adj < 0] = 0
-        layers.append(PackedLayer(adj=jnp.asarray(adj),
-                                  packed_low=jnp.asarray(packed, dt)))
+        packed_low.append(packed.astype(dt, copy=False))
     low2 = None
     if filt is not None and hasattr(filt, "encode_mid"):
         # the cascade's promote side-car: PCA rows off the hot stream
-        low2 = jnp.asarray(filt.encode_mid(g.x))
-    return PackedDB(layers=layers, low=jnp.asarray(x_low, dt),
-                    high=jnp.asarray(g.x), entry=g.entry, cfg=g.cfg,
-                    low2=low2, filter_kind=fkind)
+        low2 = filt.encode_mid(g.x)
+    return HostPacked(adj=[np.asarray(a) for a in adjs],
+                      packed_low=packed_low,
+                      low=np.asarray(x_low).astype(dt, copy=False),
+                      low2=low2, filter_kind=fkind)
 
 
 def _rank_sort_with_payload(d, p):
@@ -230,7 +256,7 @@ def _cascade_qpca(qprep, S: int):
 
 
 def _layer_init(db: PackedDB, start_d, start_i, *, ef: int, k: int,
-                CAP: int, filter_deleted: bool):
+                CAP: int, filter_deleted: bool, bitmap: bool = True):
     """The fixed-capacity SORTED layer state seeded from a start set:
     (C_d, C_i, F_d, F_i, V, Cp). Shared by ``search_layer_batched``
     (fresh per layer) and the slotted admission path (fresh per
@@ -258,8 +284,10 @@ def _layer_init(db: PackedDB, start_d, start_i, *, ef: int, k: int,
     # visited bitmap, the ASIC's SPM bitmap verbatim: one bit per node,
     # packed into int32 words; membership = one word gather per
     # candidate, insert = scatter-add of (disjoint) bit masks
-    nw = -(-N // 32)
+    nw = -(-N // 32) if bitmap else 1     # no bitmap: a dummy word
     V = jnp.zeros((B, nw), jnp.int32)
+    if not bitmap:
+        return C_d, C_i, F_d, F_i, V, jnp.full((B, k), INF)
     sw, sb = start_i // 32, start_i % 32
     V = jax.vmap(lambda v, w, m: v.at[w].add(m))(
         V, sw, jnp.where(start_i >= 0, (1 << sb).astype(jnp.int32), 0))
@@ -273,7 +301,8 @@ def _layer_init(db: PackedDB, start_d, start_i, *, ef: int, k: int,
 
 def _layer_body(db: PackedDB, layer: int, q_high, qprep, *, ef: int,
                 k: int, W: int, steps, filter_deleted: bool,
-                deferred: bool, ef_eff=None, budget=None):
+                deferred: bool, ef_eff=None, budget=None,
+                bitmap: bool = True):
     """Build the ONE-expansion-iteration body over the layer state
     tuple ``(t, C_d, C_i, F_d, F_i, V, Cp, done, nsteps, dhe)``.
 
@@ -290,7 +319,15 @@ def _layer_body(db: PackedDB, layer: int, q_high, qprep, *, ef: int,
     * ``budget`` [B] int32 — the per-slot expansion-step budget
       replacing the static ``steps`` limit (the adaptive step-budget
       hook: a stalled slot freezes without latching ``done`` and
-      resumes when the scheduler raises its budget)."""
+      resumes when the scheduler raises its budget).
+
+    ``bitmap=False`` (identity filter, no tombstones, static ef and
+    budget only) drops the visited bitmap: a candidate counts as seen
+    iff it is in C or F. The traversal is the same: any other visited
+    node has d >= F.max, which only shrinks, so it is rejected again.
+    Only the Dist.H count grows, by those re-evaluations."""
+    assert bitmap or (db.filter_kind == "none" and not filter_deleted
+                      and ef_eff is None and budget is None)
     B = q_high.shape[0]
     lay = db.layers[layer]
     M = lay.adj.shape[1]
@@ -303,6 +340,7 @@ def _layer_body(db: PackedDB, layer: int, q_high, qprep, *, ef: int,
 
     def body(state):
         t, C_d, C_i, F_d, F_i, V, Cp, done, nsteps, dhe = state
+        C_seen = C_i                        # before this step's pop
         # the acceptance/termination bound: F.max over the slot's
         # effective result width (the full compiled width when no
         # per-slot ef is active — bit-identical to the original)
@@ -373,7 +411,11 @@ def _layer_body(db: PackedDB, layer: int, q_high, qprep, *, ef: int,
             valid = (kv < VALID_MAX) & (cand >= 0)
         # -- visited check: one bit gather per candidate --
         cw, cb = jnp.maximum(cand, 0) // 32, jnp.maximum(cand, 0) % 32
-        seen = (jnp.take_along_axis(V, cw, axis=1) >> cb) & 1 != 0
+        if bitmap:
+            seen = (jnp.take_along_axis(V, cw, axis=1) >> cb) & 1 != 0
+        else:
+            seen = (cand[:, :, None] == jnp.concatenate(
+                [C_seen, F_i], 1)[:, None, :]).any(-1)
         if W > 1:
             # intra-iteration dedup (the W neighbor lists may overlap;
             # keep the first occurrence); a single list holds distinct
@@ -395,8 +437,9 @@ def _layer_body(db: PackedDB, layer: int, q_high, qprep, *, ef: int,
             dhe = dhe + valid.sum(axis=1, dtype=jnp.int32)
         # -- mark visited: disjoint bit masks (valid slots are distinct
         #    ids, so mod-2^32 add == bitwise or) --
-        V = jax.vmap(lambda v, w, m: v.at[w].add(m))(
-            V, cw, jnp.where(valid, (1 << cb).astype(jnp.int32), 0))
+        if bitmap:
+            V = jax.vmap(lambda v, w, m: v.at[w].add(m))(
+                V, cw, jnp.where(valid, (1 << cb).astype(jnp.int32), 0))
         # -- accept: d < F.max or F not full (F starts padded with INF) --
         accept = dh < bnd
         # one stacked stable sort orders the acceptees for every
@@ -447,7 +490,7 @@ def search_layer_batched(db: PackedDB, layer: int, q_high, qprep,
                          max_steps: Optional[int] = None,
                          expand_width: Optional[int] = None,
                          filter_deleted: bool = False,
-                         deferred: bool = False):
+                         deferred: bool = False, bitmap: bool = True):
     """One layer of Algorithm 1 for a batch of queries.
 
     ``qprep`` is the active filter's per-query data (PCA-projected
@@ -497,7 +540,7 @@ def search_layer_batched(db: PackedDB, layer: int, q_high, qprep,
     # --- fixed-capacity SORTED state ---
     C_d, C_i, F_d, F_i, V, Cp = _layer_init(
         db, start_d, start_i, ef=ef, k=k, CAP=CAP,
-        filter_deleted=filter_deleted)
+        filter_deleted=filter_deleted, bitmap=bitmap)
     done = jnp.zeros((B,), bool)
     nsteps = jnp.zeros((B,), jnp.int32)
     dhe = jnp.zeros((B,), jnp.int32)
@@ -509,7 +552,7 @@ def search_layer_batched(db: PackedDB, layer: int, q_high, qprep,
 
     body = _layer_body(db, layer, q_high, qprep, ef=ef, k=k, W=W,
                        steps=steps, filter_deleted=filter_deleted,
-                       deferred=deferred)
+                       deferred=deferred, bitmap=bitmap)
     out = jax.lax.while_loop(cond, body, state)
     _, _, _, F_d, F_i, _, _, _, nsteps, dhe = out
     return F_d, F_i, nsteps, dhe
@@ -547,12 +590,17 @@ def probe_neighborhoods(db, queries, qprep, ef, k,
     ep = jnp.broadcast_to(
         jnp.asarray(db.entry, jnp.int32).reshape(()), (B, 1))
     ep_d = ops.dist_h(jnp.take(db.high, ep, axis=0), queries)
+    # the wave build's snapshot (identity filter, no tombstones) needs
+    # no visited bitmap: at 2048 queries its per-step scatter is the
+    # probe's largest op on a TPU, and its state is [B, N/32] words
+    bitmap = filter_deleted or db.filter_kind != "none"
     out_d, out_i = [], []
     for layer in range(len(db.layers) - 1, -1, -1):
         ef_l = ef if layer == 0 else min(ef_upper or ef, ef)
         fd, fi, _, _ = search_layer_batched(
             db, layer, queries, qprep, ep_d, ep, ef=ef_l, k=k,
-            max_steps=2 * ef_l + 16, filter_deleted=filter_deleted)
+            max_steps=2 * ef_l + 16, filter_deleted=filter_deleted,
+            bitmap=bitmap)
         ep_d, ep = fd, fi
         if ef_l < ef:
             fd = jnp.pad(fd, ((0, 0), (0, ef - ef_l)),

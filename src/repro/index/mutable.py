@@ -49,6 +49,7 @@ from pathlib import Path
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import PHNSWConfig
@@ -191,14 +192,17 @@ class MutableIndex:
                  x_low: np.ndarray, levels: np.ndarray,
                  adj: Sequence[np.ndarray], entry: int,
                  deleted: Optional[np.ndarray] = None, *, seed: int = 0,
-                 epoch: int = 0):
+                 epoch: int = 0, device=None):
         """Build from UNPADDED arrays ([n] rows); pads to capacity and
         publishes. ``pca`` may be a bare ``PCA`` (the seed API) or any
         ``FilterSpec``; ``x_low`` is that filter's payload rows.
+        ``device`` holds the published buffers (default device when
+        None) — a sharded index puts each shard on its own.
         Prefer the ``from_graph`` / ``build`` / ``load`` classmethods."""
         n = len(x)
         cap = _next_pow2(n, cfg.min_capacity)
         self.cfg = cfg
+        self.device = device
         self.filt = _as_filter(pca, cfg)
         # PCA convenience handle (drift checks, seed callers): the
         # PCAFilter's projection, or the cascade's mid-stage projection;
@@ -245,14 +249,14 @@ class MutableIndex:
         self._publish_full()
 
     @classmethod
-    def from_graph(cls, g: HNSWGraph, pca, *, seed: int = 0
+    def from_graph(cls, g: HNSWGraph, pca, *, seed: int = 0, device=None
                    ) -> "MutableIndex":
         """Adopt a one-shot ``build_hnsw`` graph as the mutable seed.
         ``pca``: a fitted ``PCA`` or any ``FilterSpec``."""
         filt = _as_filter(pca, g.cfg)
         x_low = filt.encode(g.x)
         return cls(g.cfg, filt, g.x, x_low, g.levels, g.layers, g.entry,
-                   seed=seed)
+                   seed=seed, device=device)
 
     @classmethod
     def build(cls, x: np.ndarray, cfg: PHNSWConfig, *, seed: int = 0
@@ -300,12 +304,17 @@ class MutableIndex:
                 M = self.cfg.degree(l)
                 pl = self._dev_low.shape[1]
                 self._empty_layers[key] = (
-                    jnp.full((self.cap, M), -1, jnp.int32),
-                    jnp.zeros((self.cap, M, pl), self._dev_payload_dtype))
+                    self._put(np.full((self.cap, M), -1, np.int32)),
+                    self._put(np.zeros((self.cap, M, pl),
+                                       self._dev_payload_dtype)))
             a, p = self._empty_layers[key]
             adj.append(a)
             packed.append(p)
         return adj, packed
+
+    def _put(self, a: np.ndarray) -> jax.Array:
+        """Upload a host buffer to this index's device."""
+        return jax.device_put(a, self.device)
 
     def _publish_full(self) -> None:
         """Rebuild every device buffer (init / growth / compaction /
@@ -313,14 +322,15 @@ class MutableIndex:
         dt = self._dev_payload_dtype
         n_pub = self.top + 1
         all_rows = np.arange(self.cap)
-        self._dev_adj = [jnp.asarray(self.adj[l]) for l in range(n_pub)]
-        self._dev_packed = [jnp.asarray(self._packed_rows(l, all_rows), dt)
+        self._dev_adj = [self._put(self.adj[l]) for l in range(n_pub)]
+        self._dev_packed = [self._put(self._packed_rows(l, all_rows)
+                                      .astype(dt))
                             for l in range(n_pub)]
-        self._dev_low = jnp.asarray(self.x_low, dt)
-        self._dev_high = jnp.asarray(self.x)
-        self._dev_deleted = jnp.asarray(_pack_bitmap(self.deleted))
+        self._dev_low = self._put(self.x_low.astype(dt))
+        self._dev_high = self._put(self.x)
+        self._dev_deleted = self._put(_pack_bitmap(self.deleted))
         self._dev_low2 = None if self.x_mid is None \
-            else jnp.asarray(self.x_mid)
+            else self._put(self.x_mid)
         self._swap()
 
     def _publish_incremental(self, dirty: List[set], new_ids: np.ndarray,
@@ -593,7 +603,7 @@ class MutableIndex:
         self.__init__(self.cfg, self.filt, x, x_low, levels, adj,
                       int(entry_cands[0]), seed=int(
                           self.rng.integers(0, 2**31 - 1)),
-                      epoch=self.epoch)
+                      epoch=self.epoch, device=self.device)
         self.last_remap = remap
         return {"n_before": n_before, "n_after": self.n,
                 "tombstone_frac_before": frac_before,

@@ -33,10 +33,10 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.configs.base import PHNSWConfig
-from repro.core.distributed import (ShardedDB, distributed_search,
-                                    shard_bounds, shard_search_host)
+from repro.core.distributed import (ShardedDB, build_shard_graphs,
+                                    distributed_search, place_stacked,
+                                    shard_search_host)
 from repro.core.filters import FilterSpec, make_filter
-from repro.core.graph import build_hnsw
 from repro.distributed import faults as _faults
 from repro.index.mutable import (MutableIndex, read_snapshot,
                                  write_snapshot)
@@ -47,11 +47,16 @@ class ShardedMutableIndex:
     """P shard-local mutable indexes + one stacked device snapshot."""
 
     def __init__(self, shards: Sequence[MutableIndex], filt: FilterSpec,
-                 cfg: PHNSWConfig):
+                 cfg: PHNSWConfig, mesh=None):
+        """``mesh`` (a ``core.distributed.serving_mesh``) places every
+        stacked leaf's shard s on the device at ``model`` index s; the
+        shard indexes should hold their buffers there already (``build``
+        does that), so publishing moves nothing between devices."""
         assert len(shards) >= 1
         self.shards: List[MutableIndex] = list(shards)
         self.filt = filt
         self.cfg = cfg
+        self.mesh = mesh
         self.epoch = 0
         self._rr = 0                      # round-robin insert cursor
         self._align_capacity()
@@ -60,20 +65,25 @@ class ShardedMutableIndex:
     @classmethod
     def build(cls, x: np.ndarray, cfg: PHNSWConfig, n_shards: int, *,
               seed: int = 0, filt: Optional[FilterSpec] = None,
-              builder: Optional[str] = None) -> "ShardedMutableIndex":
+              builder: Optional[str] = None,
+              mesh=None) -> "ShardedMutableIndex":
         """Fit ONE shared filter on the full dataset, partition
         (remainder distributed), and build each shard's graph + mutable
         index independently — through the one construction pipeline
         (``builder`` defaults to ``cfg.builder``, the wave pipeline;
         equal-sized shards reuse its compiled probe program, and the
-        shard indexes' subsequent wave inserts share it too)."""
+        shard indexes' subsequent wave inserts share it too). With a
+        ``mesh`` the shards build concurrently and each shard index
+        lives on its own device."""
         filt = filt or make_filter(cfg, x, seed=seed)
-        shards = []
-        for s, (a, b) in enumerate(shard_bounds(len(x), n_shards)):
-            g = build_hnsw(x[a:b], cfg, seed=seed + s, builder=builder)
-            shards.append(MutableIndex.from_graph(g, filt,
-                                                  seed=seed + 101 * s + 1))
-        return cls(shards, filt, cfg)
+        graphs = build_shard_graphs(x, cfg, n_shards, seed=seed,
+                                    builder=builder, mesh=mesh)
+        devs = [None] * n_shards if mesh is None \
+            else list(mesh.devices.reshape(-1))
+        shards = [MutableIndex.from_graph(g, filt, seed=seed + 101 * s + 1,
+                                          device=devs[s])
+                  for s, g in enumerate(graphs)]
+        return cls(shards, filt, cfg, mesh=mesh)
 
     # ------------------------------------------------------------------
     # id space / aggregates
@@ -176,23 +186,21 @@ class ShardedMutableIndex:
         per = [s.device_layers(n_pub) for s in self.shards]
         stride = self.stride
         Pn = self.n_shards
+        stack = lambda parts: place_stacked(parts, self.mesh)
         self.epoch += 1
         self._sdb = ShardedDB(
-            adj=[jnp.stack([adj[l] for adj, _ in per])
-                 for l in range(n_pub)],
-            packed_low=[jnp.stack([pck[l] for _, pck in per])
+            adj=[stack([adj[l] for adj, _ in per]) for l in range(n_pub)],
+            packed_low=[stack([pck[l] for _, pck in per])
                         for l in range(n_pub)],
-            low=jnp.stack([s._dev_low for s in self.shards]),
-            high=jnp.stack([s._dev_high for s in self.shards]),
-            entries=jnp.asarray([s.entry for s in self.shards],
-                                jnp.int32),
-            offsets=jnp.asarray([i * stride for i in range(Pn)],
-                                jnp.int32),
-            counts=jnp.asarray([stride] * Pn, jnp.int32),
+            low=stack([s._dev_low for s in self.shards]),
+            high=stack([s._dev_high for s in self.shards]),
+            entries=stack([np.int32(s.entry) for s in self.shards]),
+            offsets=stack([np.int32(i * stride) for i in range(Pn)]),
+            counts=stack([np.int32(stride)] * Pn),
             cfg=self.cfg,
-            deleted=jnp.stack([s._dev_deleted for s in self.shards]),
+            deleted=stack([s._dev_deleted for s in self.shards]),
             low2=None if self.shards[0]._dev_low2 is None else
-            jnp.stack([s._dev_low2 for s in self.shards]),
+            stack([s._dev_low2 for s in self.shards]),
             filter_kind=self.filt.kind,
         )
         pub.set(n_layers=n_pub)

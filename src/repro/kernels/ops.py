@@ -1,15 +1,17 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Padding, block-size selection, and backend dispatch live here: on TPU the
-kernels run compiled; anywhere else they run under ``interpret=True``
-(the kernel body executes in Python on CPU — bit-faithful semantics, no
-performance claim). ``REPRO_FORCE_PALLAS_INTERPRET=1`` forces interpret
-mode for testing.
+Padding, block-size selection, and backend dispatch live here. On a TPU
+the kernels run compiled by Mosaic; elsewhere they run as their jnp
+oracles (``kernels/ref.py``) by default, or under ``interpret=True``
+when asked (the kernel body executes in Python on CPU — bit-faithful
+semantics, no performance claim). ``kernel_path`` is the one place
+that decides.
 """
 from __future__ import annotations
 
 import functools
 import os
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -27,26 +29,31 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.decode_attention import decode_attention_pallas
 
 
-def _interpret() -> bool:
-    if os.environ.get("REPRO_FORCE_PALLAS_INTERPRET"):
-        return True
-    return jax.default_backend() != "tpu"
-
-
-def _use_ref() -> bool:
-    """On non-TPU backends, route to the jnp oracles by default: interpret
-    mode executes the kernel body in Python per grid step (correct but
-    ~100x slower), which would dominate CPU tests/benchmarks. Set
-    REPRO_FORCE_PALLAS_INTERPRET=1 to exercise the Pallas path on CPU
-    (the kernel test suite does)."""
-    if os.environ.get("REPRO_FORCE_PALLAS_INTERPRET"):
-        return False
+def kernel_path() -> str:
+    """What the kernel wrappers trace to right now: "compiled" (Pallas
+    lowered by Mosaic, TPU only), "interpret" (Pallas interpret mode) or
+    "ref" (the jnp oracles). Off the TPU the oracles serve by default:
+    interpret mode runs the kernel body in Python per grid step (~100x
+    slower). ``REPRO_FORCE_PALLAS_INTERPRET=1`` forces interpret mode
+    (the kernel test suite uses it); ``REPRO_KERNEL_IMPL=ref`` forces
+    the oracles and ``=pallas`` the kernels. Read at trace time —
+    ``jax.clear_caches()`` between switches in one process. On a TPU a
+    forced non-compiled path warns, since no deployment runs it."""
+    on_tpu = jax.default_backend() == "tpu"
     impl = os.environ.get("REPRO_KERNEL_IMPL", "auto")
-    if impl == "ref":
-        return True
-    if impl == "pallas":
-        return False
-    return jax.default_backend() != "tpu"
+    if os.environ.get("REPRO_FORCE_PALLAS_INTERPRET"):
+        path = "interpret"
+    elif impl == "ref":
+        path = "ref"
+    elif on_tpu:
+        path = "compiled"
+    else:
+        path = "interpret" if impl == "pallas" else "ref"
+    if on_tpu and path != "compiled":
+        warnings.warn(f"Pallas kernels traced as {path!r} on a TPU "
+                      "(REPRO_FORCE_PALLAS_INTERPRET or REPRO_KERNEL_IMPL "
+                      "is set)", RuntimeWarning, stacklevel=2)
+    return path
 
 
 def _pad_batch(x, mult: int):
@@ -57,60 +64,67 @@ def _pad_batch(x, mult: int):
     return x, B
 
 
-def _pick_block_b(B: int, row_elems: int, cap_elems: int = 1 << 20) -> int:
-    """Every traversal kernel holds O(row_elems) VMEM per batch row
-    (comparison matrices, neighbor blocks, ...); shrink the batch block
-    until the per-block footprint fits under ``cap_elems`` elements."""
-    bb = 8
-    while bb > 1 and bb * row_elems > cap_elems:
-        bb //= 2
-    return bb
+def _pick_block_b(B: int) -> int:
+    """Batch rows per grid step. Mosaic tiles the second-to-last dim of
+    the 2-D operand blocks ([bb, M], [bb, k], [bb, 1]) in sublanes of 8,
+    so a block is 8 rows — or the whole batch when it has fewer (a block
+    equal to the full dim is always legal). Larger batches pad up to a
+    multiple of 8. The per-row VMEM footprint never shrinks the block
+    below that: at the real widths (M0=32, S=16, D<=960) 8 rows fit
+    (tests/test_tpu_compile.py)."""
+    return min(B, 8)
 
 
 @jax.jit
 def dist_l(x, q):
     """x: [B, M, dl]; q: [B, dl] -> [B, M] f32 squared distances."""
-    if _use_ref():
+    path = kernel_path()
+    if path == "ref":
         return ref.dist_l_ref(x, q)
-    bb = _pick_block_b(x.shape[0], x.shape[1] * x.shape[2])
+    bb = _pick_block_b(x.shape[0])
     xp, B = _pad_batch(x, bb)
     qp, _ = _pad_batch(q, bb)
-    return dist_l_pallas(xp, qp, block_b=bb, interpret=_interpret())[:B]
+    return dist_l_pallas(xp, qp, block_b=bb,
+                         interpret=path == "interpret")[:B]
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def ksort_l(d, k: int):
     """d: [B, M] -> (vals [B, k] ascending, idx [B, k])."""
-    if _use_ref():
+    path = kernel_path()
+    if path == "ref":
         return ref.ksort_l_ref(d, k)
-    bb = _pick_block_b(d.shape[0], d.shape[1] * d.shape[1])
+    bb = _pick_block_b(d.shape[0])
     dp, B = _pad_batch(d, bb)
-    v, i = ksort_l_pallas(dp, k, block_b=bb, interpret=_interpret())
+    v, i = ksort_l_pallas(dp, k, block_b=bb,
+                          interpret=path == "interpret")
     return v[:B], i[:B]
 
 
 @jax.jit
 def dist_h(x, q):
     """x: [B, K, D]; q: [B, D] -> [B, K] f32 squared distances."""
-    if _use_ref():
+    path = kernel_path()
+    if path == "ref":
         return ref.dist_h_ref(x, q)
-    bb = _pick_block_b(x.shape[0], x.shape[1] * x.shape[2])
+    bb = _pick_block_b(x.shape[0])
     xp, B = _pad_batch(x, bb)
     qp, _ = _pad_batch(q, bb)
-    return dist_h_pallas(xp, qp, block_b=bb, interpret=_interpret())[:B]
+    return dist_h_pallas(xp, qp, block_b=bb,
+                         interpret=path == "interpret")[:B]
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def fused_filter(x, q, k: int):
     """pHNSW step 2: x [B, M, dl], q [B, dl] -> top-k (vals, idx)."""
-    if _use_ref():
+    path = kernel_path()
+    if path == "ref":
         return ref.fused_filter_ref(x, q, k)
-    bb = _pick_block_b(x.shape[0],
-                       x.shape[1] * (x.shape[1] + x.shape[2]))
+    bb = _pick_block_b(x.shape[0])
     xp, B = _pad_batch(x, bb)
     qp, _ = _pad_batch(q, bb)
     v, i = fused_filter_pallas(xp, qp, k, block_b=bb,
-                               interpret=_interpret())
+                               interpret=path == "interpret")
     return v[:B], i[:B]
 
 
@@ -121,16 +135,16 @@ def fused_expand(x, q, valid, th, k: int):
     x: [B, M, dl]; q: [B, dl]; valid: [B, M] bool; th: [B] f32.
     Returns (vals [B, k] ascending, idx [B, k]); filtered-out slots get
     vals >= constants.VALID_MAX."""
-    if _use_ref():
+    path = kernel_path()
+    if path == "ref":
         return ref.fused_expand_ref(x, q, valid, th, k)
-    bb = _pick_block_b(x.shape[0],
-                       x.shape[1] * (x.shape[1] + x.shape[2]))
+    bb = _pick_block_b(x.shape[0])
     xp, B = _pad_batch(x, bb)
     qp, _ = _pad_batch(q, bb)
     vp, _ = _pad_batch(valid.astype(jnp.int32), bb)
     tp, _ = _pad_batch(th[:, None].astype(jnp.float32), bb)
     v, i = fused_expand_pallas(xp, qp, vp, tp, k, block_b=bb,
-                               interpret=_interpret())
+                               interpret=path == "interpret")
     return v[:B], i[:B]
 
 
@@ -142,17 +156,17 @@ def pq_adc_expand(codes, lut, valid, th, k: int):
     codes: [B, M, S] integer PQ codes; lut: [B, S, 256] f32; valid:
     [B, M] bool; th: [B] f32. Returns (vals [B, k] ascending, idx
     [B, k]); filtered-out slots get vals >= constants.VALID_MAX."""
-    if _use_ref():
+    path = kernel_path()
+    if path == "ref":
         return ref.pq_adc_expand_ref(codes, lut, valid, th, k)
     B, M, S = codes.shape
-    # the one-hot ADC contraction holds [bb, M, S, 256] in VMEM
-    bb = _pick_block_b(B, M * S * 256 + M * M)
+    bb = _pick_block_b(B)
     cp, _ = _pad_batch(codes.astype(jnp.int32), bb)
     lp, _ = _pad_batch(lut.astype(jnp.float32), bb)
     vp, _ = _pad_batch(valid.astype(jnp.int32), bb)
     tp, _ = _pad_batch(th[:, None].astype(jnp.float32), bb)
     v, i = pq_adc_expand_pallas(cp, lp, vp, tp, k, block_b=bb,
-                                interpret=_interpret())
+                                interpret=path == "interpret")
     return v[:B], i[:B]
 
 
@@ -171,16 +185,17 @@ def merge_topk_sorted(d_a, i_a, d_b, i_b, k: int):
     if d_b.shape[1] > k:
         # only the first k of a sorted b can reach a k-wide output
         d_b, i_b = d_b[:, :k], i_b[:, :k]
-    if _use_ref():
+    path = kernel_path()
+    if path == "ref":
         return ref.merge_topk_sorted_ref(d_a, i_a, d_b, i_b, k)
     Na, Nb = d_a.shape[1], d_b.shape[1]
-    bb = _pick_block_b(d_a.shape[0], Na * Nb + k * (Na + Nb))
+    bb = _pick_block_b(d_a.shape[0])
     dap, B = _pad_batch(d_a, bb)
     iap, _ = _pad_batch(i_a, bb)
     dbp, _ = _pad_batch(d_b, bb)
     ibp, _ = _pad_batch(i_b, bb)
     v, i = merge_sorted_pallas(dap, iap, dbp, ibp, k, block_b=bb,
-                               interpret=_interpret())
+                               interpret=path == "interpret")
     return v[:B], i[:B]
 
 
@@ -188,19 +203,22 @@ def merge_topk_sorted(d_a, i_a, d_b, i_b, k: int):
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     bq: int = 128, bk: int = 128):
     """q: [B, H, S, d]; k, v: [B, H, T, d] -> [B, H, S, d]."""
-    if _use_ref():
+    path = kernel_path()
+    if path == "ref":
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  bq=bq, bk=bk, interpret=_interpret())
+                                  bq=bq, bk=bk,
+                                  interpret=path == "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=("bk",))
 def decode_attention(q, k, v, length, *, bk: int = 512):
     """q: [B, H, d]; k, v: [B, H, T, d]; length [B] -> [B, H, d]."""
-    if _use_ref():
+    path = kernel_path()
+    if path == "ref":
         return ref.decode_attention_ref(q, k, v, length)
     return decode_attention_pallas(q, k, v, length, bk=bk,
-                                   interpret=_interpret())
+                                   interpret=path == "interpret")
 
 
 # re-export the oracles for tests/benchmarks

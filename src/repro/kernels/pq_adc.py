@@ -33,7 +33,10 @@ def _pq_adc_expand_kernel(codes_ref, lut_ref, valid_ref, th_ref,
     # -- ADC: one-hot gather-accumulate over the 256 centroid slots --
     cc = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, 256), 3)
     onehot = codes[:, :, :, None] == cc                  # [bb, M, S, 256]
-    d = jnp.sum(jnp.where(onehot, lut[:, None, :, :], 0.0), axis=(2, 3))
+    # centroid slots first, then subspaces: Mosaic reduces over both
+    # trailing dims at once only into a trailing axis of size 1
+    d = jnp.sum(jnp.sum(jnp.where(onehot, lut[:, None, :, :], 0.0),
+                        axis=3), axis=2)                 # [bb, M]
     d = jnp.where(valid & (d < th), d, INF)              # filter
     val_ref[...], idx_ref[...] = ksort_block(d, k)       # kSort.L
 

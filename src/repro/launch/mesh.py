@@ -34,9 +34,18 @@ def make_production_mesh(*, multi_pod: bool = False, n_pods: int = 2):
             "the dry-run entrypoint must set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, _auto(axes), devices=devices[:n])
 
 
 def make_host_mesh():
     """1-device mesh for smoke tests / CPU examples."""
-    return jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    axes = ("data", "model")
+    return jax.make_mesh((1, 1), axes, _auto(axes),
+                         devices=jax.devices()[:1])
+
+
+def _auto(axes):
+    """Auto axis types: the LM code places values with
+    ``with_sharding_constraint``, which Explicit axes (the
+    ``jax.make_mesh`` default) refuse."""
+    return (jax.sharding.AxisType.Auto,) * len(axes)

@@ -84,6 +84,40 @@ def test_wave_build_determinism():
         np.testing.assert_array_equal(a1, a2)
 
 
+@pytest.mark.parametrize("ef,ef_upper", [(32, 16), (100, None)])
+def test_probe_without_bitmap_matches_bitmap(build8k, monkeypatch, ef,
+                                             ef_upper):
+    """The wave probe over an identity-filter snapshot dedups by C/F
+    membership instead of a visited bitmap: its neighborhoods are
+    bit-equal to the bitmap traversal's at every layer."""
+    import jax
+    from repro.core import search_jax as sj
+    cfg, x, g, _, q, _ = build8k
+    n = len(x)
+    top = int(g.levels.max()) + 1
+    db = sj.PackedDB(
+        layers=[sj.PackedLayer(adj=jnp.asarray(a),
+                               packed_low=jnp.zeros((n, a.shape[1], 0)))
+                for a in g.layers[:top]],
+        low=jnp.zeros((n, 0)), high=jnp.asarray(x), entry=g.entry,
+        cfg=cfg, deleted=None, filter_kind="none")
+    qx = jnp.asarray(make_sift_like(64, seed=21))
+    probe = lambda: [np.asarray(a) for a in sj.probe_neighborhoods(
+        db, qx, jnp.zeros((64, 0)), ef, 16, filter_deleted=False,
+        ef_upper=ef_upper)]
+    jax.clear_caches()
+    lists = probe()
+    plain = sj.search_layer_batched
+    monkeypatch.setattr(sj, "search_layer_batched",
+                        lambda *a, **k: plain(*a, **{**k, "bitmap": True}))
+    jax.clear_caches()
+    bitmap = probe()
+    jax.clear_caches()
+    assert (lists[1][0] >= 0).any()
+    for a, b in zip(lists, bitmap):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_single_wave_build_is_searchable():
     """n < wave_size: one wave against a 1-node snapshot — the
     intra-wave block alone must produce a connected, searchable

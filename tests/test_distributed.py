@@ -670,6 +670,64 @@ def test_sharded_search_multidevice():
     assert "MESH==HOST OK" in out.stdout
 
 
+SUBPROCESS_PLACEMENT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import numpy as np, jax
+    from repro.configs.base import PHNSWConfig
+    from repro.core.distributed import (build_sharded, serving_mesh,
+                                        shard_search_host)
+    from repro.core.pca import fit_pca
+    from repro.data.vectors import make_sift_like, make_queries
+    from repro.index import ShardedMutableIndex
+
+    cfg = PHNSWConfig(name="t", n_points=2000, ef_construction=40)
+    x = make_sift_like(2000); q = make_queries(x, 16)
+    mesh = serving_mesh(4)
+    devs = [str(d) for d in mesh.devices.reshape(-1)]
+    placed = lambda a: [str(s.device) for s in
+                        sorted(a.addressable_shards,
+                               key=lambda s: s.index[0].start)]
+    # frozen: concurrent per-device builds == the sequential build
+    pca = fit_pca(x, 15)
+    sdb = build_sharded(x, cfg, pca, 4, mesh=mesh)
+    ref = build_sharded(x, cfg, pca, 4)
+    for leaf in (sdb.high, sdb.adj[0], sdb.packed_low[0], sdb.entries):
+        assert placed(leaf) == devs, placed(leaf)
+    np.testing.assert_array_equal(np.asarray(sdb.adj[0]),
+                                  np.asarray(ref.adj[0]))
+    # mutable: each shard index lives on its own device, before and
+    # after a mutation's republish
+    idx = ShardedMutableIndex.build(x, cfg, 4, seed=1, mesh=mesh)
+    assert [str(*s._dev_high.devices()) for s in idx.shards] == devs
+    gids = idx.upsert(make_sift_like(40, seed=9))
+    idx.delete(gids[:5])
+    assert placed(idx.sdb.high) == devs and placed(idx.sdb.deleted) == devs
+    fd_m, fi_m = idx.search(q, mesh=mesh)
+    one = jax.device_put(idx.sdb, jax.devices()[0])
+    fd_h, fi_h = shard_search_host(one, jax.numpy.asarray(q),
+                                   filt=idx.filt)
+    np.testing.assert_array_equal(np.asarray(fi_m), np.asarray(fi_h))
+    np.testing.assert_array_equal(np.asarray(fd_m), np.asarray(fd_h))
+    print("PLACEMENT OK")
+""")
+
+
+def test_sharded_placement_multidevice():
+    """4 simulated devices: with a mesh, every stacked leaf of the frozen
+    and the mutable sharded index holds shard s on device s (never
+    staged whole on one device), the concurrent per-device builds give
+    the sequential build's graphs, and the mesh search stays bit-equal
+    to the single-device shard loop after a mutation."""
+    out = subprocess.run([sys.executable, "-c", SUBPROCESS_PLACEMENT],
+                         capture_output=True, text=True,
+                         env={**__import__("os").environ,
+                              "PYTHONPATH": "src"},
+                         cwd=Path(__file__).resolve().parents[1])
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "PLACEMENT OK" in out.stdout
+
+
 SUBPROCESS_REMESH = textwrap.dedent("""
     import os, tempfile
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
