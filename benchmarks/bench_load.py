@@ -34,9 +34,6 @@ Protocol:
    p99 ratio the serve gate compares. The same samples still land in
    the obs histograms, so the scrape exporter tells the same story at
    bucket resolution.
-4. **Cost-model bridge**: one ``return_stats`` batch is folded through
-   ``repro.obs.bridge`` (steps / Dist.H histograms + predicted-vs-
-   measured query cost — the autotuner's calibration feed).
 
 The canonical 8k run appends the tracked ``load`` section of
 ``BENCH_table3.json`` (own append-only history, like ``build`` /
@@ -230,13 +227,12 @@ def main(n_points: int = 8_000, n_queries: int = 64,
          offered_fracs: Sequence[float] = (0.3, 0.7, 0.8),
          n_requests: int = 120, calib_reps: int = 6,
          sched_slots: int = 64):
-    from repro.core.search_jax import build_packed, search_batched
+    from repro.core.search_jax import build_packed
     from repro.obs import (Registry, Tracer, parse_prometheus,
-                           prometheus_families, record_search_stats,
-                           to_prometheus)
+                           prometheus_families, to_prometheus)
     from repro.serve.vector_service import VectorSearchService
 
-    cfg, x, g, pca, x_low, q, gt = load_bench_db(n_points, n_queries)
+    _, x, g, pca, x_low, q, gt = load_bench_db(n_points, n_queries)
     rng = np.random.default_rng(seed)
     reg = Registry()
     tracer = Tracer()
@@ -369,23 +365,6 @@ def main(n_points: int = 8_000, n_queries: int = 64,
     rows.append(("load/recompiles", 0.0,
                  f"steady_state={sum(max(r, 0) for r in recompiles)}"))
 
-    # ---- device-telemetry bridge: predicted vs measured cost ----
-    import jax.numpy as jnp
-    qd = jnp.asarray(q[:req_size])
-    qp = jnp.asarray(svc.filt.prepare(np.asarray(q[:req_size])))
-    search_batched(db, qd, qp, return_stats=True)[1].block_until_ready()
-    t0 = time.perf_counter()
-    _, fi, st = search_batched(db, qd, qp, return_stats=True)
-    fi.block_until_ready()
-    wall = time.perf_counter() - t0
-    summary = record_search_stats(st, wall_s=wall, registry=reg,
-                                  cfg=cfg, filt=svc.filt)
-    rows.append(("obs/cost_model", summary["measured_us"],
-                 f"predicted_us={summary['predicted_us']:.1f};"
-                 f"ratio={summary['cost_ratio']:.2f};"
-                 f"steps_mean={summary['steps_mean']:.1f};"
-                 f"dist_h_mean={summary['dist_h_mean']:.1f}"))
-
     # ---- exporter: render, self-check the parse, optionally dump ----
     text = to_prometheus(reg)
     parsed = parse_prometheus(text)
@@ -415,7 +394,6 @@ def main(n_points: int = 8_000, n_queries: int = 64,
             "recompiles": recompiles,
             "mixed_k": pt_mk,
             "overhead": ab,
-            "cost_model": summary,
         }
         p = Path(json_path)
         doc = {}

@@ -1,7 +1,7 @@
 """Unified observability plane (DESIGN.md § Observability): metrics
-registry + per-request trace spans + exporters + the device-telemetry
-cost bridge. Dependency-free (numpy only) — importable from kernels,
-serving, and benchmarks alike."""
+registry + per-request trace spans + exporters. Dependency-free (numpy
+only; an enabled tracer imports JAX for its profiler annotations) —
+importable from kernels, serving, and benchmarks alike."""
 from repro.obs.metrics import (Counter, Family, Gauge, Histogram,
                                ObsEvent, Registry, counter,
                                default_registry, emit_event, gauge,
@@ -9,7 +9,6 @@ from repro.obs.metrics import (Counter, Family, Gauge, Histogram,
 from repro.obs.trace import NULL_SPAN, NULL_TRACER, Span, Tracer
 from repro.obs.export import (parse_prometheus, prometheus_families,
                               snapshot, snapshot_json, to_prometheus)
-from repro.obs.bridge import predicted_query_ns, record_search_stats
 
 __all__ = [
     "Counter", "Family", "Gauge", "Histogram", "ObsEvent", "Registry",
@@ -17,5 +16,4 @@ __all__ = [
     "NULL_SPAN", "NULL_TRACER", "Span", "Tracer",
     "parse_prometheus", "prometheus_families", "snapshot",
     "snapshot_json", "to_prometheus",
-    "predicted_query_ns", "record_search_stats",
 ]

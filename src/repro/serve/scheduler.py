@@ -61,10 +61,12 @@ from typing import Deque, Dict, List, Optional
 import dataclasses as _dc
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.core import search_jax as sj
 from repro.core.distributed import stacked_db_view
+from repro.obs import NULL_SPAN
 
 
 class SchedulerUnsupported(RuntimeError):
@@ -89,7 +91,10 @@ class Completion:
     """One retired query. ``ids``/``dists`` are the top-``k`` answer
     (GLOBAL ids on sharded backends). ``forced`` marks a query retired
     at the static step bound without latching ``done`` (exactly what
-    the synchronous program would have returned for it)."""
+    the synchronous program would have returned for it). ``dist_h``
+    is the exact count of full-dimension (Dist.H) rows evaluated for
+    the query, the descent's and any deferred re-rank's included: the
+    synchronous path's ``dist_h_evals``."""
     rid: int
     ids: np.ndarray
     dists: np.ndarray
@@ -98,6 +103,7 @@ class Completion:
     forced: bool = False
     degraded: bool = False
     coverage: float = 1.0
+    dist_h: int = 0
 
 
 class StreamScheduler:
@@ -185,6 +191,7 @@ class StreamScheduler:
         self._queue: Deque[_Pending] = deque()
         self._next_rid = 0
         self._escalated = False
+        self._budget_stale = False     # host budgets not yet uploaded
         self._live_mask: Optional[np.ndarray] = None   # test override
         D = int(snap.high.shape[-1])
         self._D = D
@@ -247,28 +254,42 @@ class StreamScheduler:
         self._live_mask = np.asarray(mask, bool)
 
     def _admit_step_call(self, dbv, q_new, slot_ids, ef_eff, budget,
-                         width):
-        qp = self.svc.filt.prepare(q_new)
-        args = (jnp.asarray(q_new), jnp.asarray(qp),
-                jnp.asarray(slot_ids), jnp.asarray(ef_eff),
-                jnp.asarray(budget))
-        fn = sj._slot_admit_step_sharded_jit if self.sharded \
-            else sj._slot_admit_step_jit
-        return fn(dbv, self.state, *args, width, self.quantum, self.W,
-                  self.deferred)
+                         width, span=NULL_SPAN):
+        with span.child("sched.prepare", rows=width):
+            qp = self.svc.filt.prepare(q_new)
+        with span.child("sched.launch", program="admit_step",
+                        width=width):
+            self._push_budget()
+            args = (jnp.asarray(q_new), jnp.asarray(qp),
+                    jnp.asarray(slot_ids), jnp.asarray(ef_eff),
+                    jnp.asarray(budget))
+            fn = sj._slot_admit_step_sharded_jit if self.sharded \
+                else sj._slot_admit_step_jit
+            return fn(dbv, self.state, *args, width, self.quantum,
+                      self.W, self.deferred)
 
-    def _step_call(self, dbv, width):
-        if width >= self.S:
-            fn = sj._slot_step_sharded_jit if self.sharded \
-                else sj._slot_step_jit
-            return fn(dbv, self.state, self.quantum, self.W,
+    def _step_call(self, dbv, width, span=NULL_SPAN):
+        full = width >= self.S
+        with span.child("sched.launch",
+                        program="step" if full else "step_prefix",
+                        width=width):
+            self._push_budget()
+            if full:
+                fn = sj._slot_step_sharded_jit if self.sharded \
+                    else sj._slot_step_jit
+                return fn(dbv, self.state, self.quantum, self.W,
+                          self.deferred)
+            fn = sj._slot_step_prefix_sharded_jit if self.sharded \
+                else sj._slot_step_prefix_jit
+            return fn(dbv, self.state, width, self.quantum, self.W,
                       self.deferred)
-        fn = sj._slot_step_prefix_sharded_jit if self.sharded \
-            else sj._slot_step_prefix_jit
-        return fn(dbv, self.state, width, self.quantum, self.W,
-                  self.deferred)
 
     def _push_budget(self) -> None:
+        """Upload the host budgets an escalation changed, just before
+        the next compiled call reads them."""
+        if not self._budget_stale:
+            return
+        self._budget_stale = False
         b = jnp.asarray(self._budget)
         if self.sharded:
             b = jnp.broadcast_to(b, self.state.budget.shape)
@@ -347,49 +368,58 @@ class StreamScheduler:
         and advance the bank — ONE fused compiled call when there are
         arrivals, a prefix step otherwise, both at the smallest ladder
         width covering the highest live slot."""
-        free = np.nonzero(self._rid_of < 0)[0]
-        take: List[_Pending] = []
-        if len(free) and self._queue:
-            now = time.monotonic()
-            while self._queue and len(take) < len(free):
-                p = self._queue.popleft()
-                if p.deadline is not None and now > p.deadline:
-                    self._c_shed.labels(reason="deadline").inc()
-                    span.event("shed", rid=p.rid)
-                    continue
-                take.append(p)
-        for row, p in enumerate(take):
-            s = int(free[row])
-            self._rid_of[s] = p.rid
-            self._budget[s] = self._initial_budget(p.ef_eff)
-            self._cap[s] = self._static_cap(p.ef_eff)
-            self._keep[s] = p.ef_eff * self.rm
-            self._meta[p.rid] = p
-        occ = np.nonzero(self._rid_of >= 0)[0]
-        if not len(occ):
-            self._g_depth.set(len(self._queue))
-            return 0
-        wd = next(w for w in self.rungs if w >= int(occ[-1]) + 1)
-        if take:
-            q_new = np.zeros((wd, self._D), np.float32)
-            slot_ids = np.full(wd, self.S, np.int32)
-            ef_eff = np.full(wd, self.EFW, np.int32)
-            budget = np.zeros(wd, np.int32)
+        with span.child("sched.admit") as adm:
+            free = np.nonzero(self._rid_of < 0)[0]
+            take: List[_Pending] = []
+            if len(free) and self._queue:
+                now = time.monotonic()
+                while self._queue and len(take) < len(free):
+                    p = self._queue.popleft()
+                    if p.deadline is not None and now > p.deadline:
+                        self._c_shed.labels(reason="deadline").inc()
+                        adm.event("shed", rid=p.rid)
+                        continue
+                    take.append(p)
+            if adm:
+                # the wait from each request's scheduled arrival to
+                # its slot
+                waits = [now - p.t_sched for p in take] or [0.0]
+                adm.set(admitted=len(take),
+                        queued_ms=1e3 * sum(waits),
+                        queued_max_ms=1e3 * max(waits))
             for row, p in enumerate(take):
                 s = int(free[row])
-                q_new[row] = p.q
-                slot_ids[row] = s
-                # deferred slots hold the WIDE filter-space pool, so
-                # the effective ef register scales with it
-                ef_eff[row] = p.ef_eff * self.wide
-                budget[row] = self._budget[s]
-                p.q = None
+                self._rid_of[s] = p.rid
+                self._budget[s] = self._initial_budget(p.ef_eff)
+                self._cap[s] = self._static_cap(p.ef_eff)
+                self._keep[s] = p.ef_eff * self.rm
+                self._meta[p.rid] = p
+            occ = np.nonzero(self._rid_of >= 0)[0]
+            if not len(occ):
+                self._g_depth.set(len(self._queue))
+                return 0
+            wd = next(w for w in self.rungs if w >= int(occ[-1]) + 1)
+            if take:
+                q_new = np.zeros((wd, self._D), np.float32)
+                slot_ids = np.full(wd, self.S, np.int32)
+                ef_eff = np.full(wd, self.EFW, np.int32)
+                budget = np.zeros(wd, np.int32)
+                for row, p in enumerate(take):
+                    s = int(free[row])
+                    q_new[row] = p.q
+                    slot_ids[row] = s
+                    # deferred slots hold the WIDE filter-space pool,
+                    # so the effective ef register scales with it
+                    ef_eff[row] = p.ef_eff * self.wide
+                    budget[row] = self._budget[s]
+                    p.q = None
+        span.set(width=wd, in_flight=len(occ))
+        if take:
             self.state = self._admit_step_call(dbv, q_new, slot_ids,
-                                               ef_eff, budget, wd)
+                                               ef_eff, budget, wd, span)
             self._c_adm.inc(len(take))
-            span.set(admitted=len(take))
         else:
-            self.state = self._step_call(dbv, wd)
+            self.state = self._step_call(dbv, wd, span)
         self._g_depth.set(len(self._queue))
         return len(take)
 
@@ -398,8 +428,9 @@ class StreamScheduler:
         occupied = self._rid_of >= 0
         if not occupied.any():
             return []
-        done = np.asarray(self.state.done)
-        ns = np.asarray(self.state.nsteps)
+        with span.child("sched.wait"):
+            done, ns, dhe = jax.device_get(
+                (self.state.done, self.state.nsteps, self.state.dhe))
         live = self._live()
         if self.sharded:
             if live.any():
@@ -408,8 +439,9 @@ class StreamScheduler:
             else:
                 done_eff = np.ones(self.S, bool)
                 ns_eff = ns.max(axis=0)
+            dhe_eff = dhe[live].sum(axis=0)
         else:
-            done_eff, ns_eff = done, ns
+            done_eff, ns_eff, dhe_eff = done, ns, dhe
         finished = occupied & done_eff
         # budget escalation: an unconverged slot that spent its budget
         # doubles it (up to the static bound); at the bound it is
@@ -428,66 +460,87 @@ class StreamScheduler:
                 else:
                     forced[s] = True
             if dirty:
-                self._push_budget()
+                self._budget_stale = True
                 self._escalated = True
         finished = finished | forced
         if not finished.any():
             return []
-        fd = np.asarray(self.state.F_d)
-        fi = np.asarray(self.state.F_i)
         if self.deferred:
+            with span.child("sched.wait"):
+                fi = np.asarray(self.state.F_i)
             # the deferred promote (cascade) + Dist.H passes, batched
             # over THIS tick's retiring slots at the full bank width
             # (non-retiring rows ride as fi = -1 pads — pure data, one
             # compiled shape): the exact final blocks of the
             # synchronous deferred program, so results are bit-equal
-            db = self.svc.db
-            fi_b = jnp.asarray(np.where(finished[:, None], fi, -1))
-            if self.cascade:
-                keep = np.where(finished, self._keep, 0).astype(np.int32)
-                _, fi_b = sj._retire_promote_jit(
-                    db, self.state.qprep, fi_b, jnp.asarray(keep))
-            rd, ri, _ = sj._retire_rerank_jit(db, self.state.q_high,
-                                              fi_b)
-            fd, fi = np.asarray(rd), np.asarray(ri)
-        degraded = self.sharded and bool(~live.all())
-        cov = self.svc._coverage(live) if degraded else 1.0
-        now = time.monotonic()
-        out: List[Completion] = []
-        for s in np.nonzero(finished)[0]:
-            p = self._meta.pop(int(self._rid_of[s]))
-            kq = p.k
-            if self.sharded:
-                ds = np.concatenate([fd[pp, s] for pp in
-                                     np.nonzero(live)[0]])
-                gs = np.concatenate(
-                    [np.where(fi[pp, s] >= 0,
-                              fi[pp, s] + self._offsets[pp], -1)
-                     for pp in np.nonzero(live)[0]])
-                order = np.argsort(ds, kind="stable")[:kq]
-                ids, dists = gs[order], ds[order]
-            else:
-                ids, dists = fi[s, :kq].copy(), fd[s, :kq].copy()
-            lat = (now - p.t_sched) * 1e3
-            out.append(Completion(
-                rid=p.rid, ids=ids, dists=dists, latency_ms=lat,
-                steps=int(ns_eff[s]), forced=bool(forced[s]),
-                degraded=degraded, coverage=cov))
-            self.steps_hist.observe(float(ns_eff[s]))
-            self.svc.stats.record_request(1, lat)
-            if degraded:
-                self.svc.stats.record_degraded(cov)
-            self._rid_of[s] = -1
-            self._budget[s] = 0
-        self._c_ret.inc(len(out))
-        if out:
-            span.set(retired=len(out))
+            with span.child("sched.rerank") as rr:
+                db = self.svc.db
+                fi_b = jnp.asarray(np.where(finished[:, None], fi, -1))
+                if self.cascade:
+                    keep = np.where(finished, self._keep,
+                                    0).astype(np.int32)
+                    _, fi_b = sj._retire_promote_jit(
+                        db, self.state.qprep, fi_b, jnp.asarray(keep))
+                rd, ri, rows = sj._retire_rerank_jit(
+                    db, self.state.q_high, fi_b)
+                with rr.child("sched.wait"):
+                    fd, fi, rows = jax.device_get((rd, ri, rows))
+                if rr:
+                    rr.set(rows=int(rows.sum()))
+            dhe_eff = dhe_eff + rows
+        else:
+            with span.child("sched.wait"):
+                fd, fi = jax.device_get((self.state.F_d, self.state.F_i))
+        with span.child("sched.complete") as cs:
+            degraded = self.sharded and bool(~live.all())
+            cov = self.svc._coverage(live) if degraded else 1.0
+            now = time.monotonic()
+            out: List[Completion] = []
+            for s in np.nonzero(finished)[0]:
+                p = self._meta.pop(int(self._rid_of[s]))
+                kq = p.k
+                if self.sharded:
+                    ds = np.concatenate([fd[pp, s] for pp in
+                                         np.nonzero(live)[0]])
+                    gs = np.concatenate(
+                        [np.where(fi[pp, s] >= 0,
+                                  fi[pp, s] + self._offsets[pp], -1)
+                         for pp in np.nonzero(live)[0]])
+                    order = np.argsort(ds, kind="stable")[:kq]
+                    ids, dists = gs[order], ds[order]
+                else:
+                    ids, dists = fi[s, :kq].copy(), fd[s, :kq].copy()
+                lat = (now - p.t_sched) * 1e3
+                out.append(Completion(
+                    rid=p.rid, ids=ids, dists=dists, latency_ms=lat,
+                    steps=int(ns_eff[s]), forced=bool(forced[s]),
+                    degraded=degraded, coverage=cov,
+                    dist_h=int(dhe_eff[s])))
+                self.steps_hist.observe(float(ns_eff[s]))
+                self.svc.stats.record_request(1, lat)
+                if degraded:
+                    self.svc.stats.record_degraded(cov)
+                self._rid_of[s] = -1
+                self._budget[s] = 0
+            self._c_ret.inc(len(out))
+            if cs:
+                cs.set(retired=len(out),
+                       dist_h_rows=sum(c.dist_h for c in out))
         return out
 
     def tick(self) -> List[Completion]:
         """One scheduler round: admit -> step -> escalate/retire.
         Returns the queries that completed this round (out-of-order by
-        design — exactly-once per rid)."""
+        design — exactly-once per rid).
+
+        Traced, the ``sched.tick`` root's children cover the round:
+        ``sched.admit`` (queue, sheds, slot bookkeeping, staging),
+        ``sched.prepare`` (the filter's host-side query prep),
+        ``sched.launch`` (uploads and the compiled call's dispatch),
+        ``sched.wait`` (the host blocked on the device or its
+        readback), ``sched.rerank`` (the deferred retirement passes)
+        and ``sched.complete`` (the answers and their accounting); the
+        escalation passes add launch / wait / complete again."""
         span = self.tracer.span("sched.tick")
         with span:
             dbv = self._db()
@@ -505,10 +558,11 @@ class StreamScheduler:
                     break
                 wd = next(w for w in self.rungs
                           if w >= int(occ[-1]) + 1)
-                self.state = self._step_call(dbv, wd)
+                self.state = self._step_call(dbv, wd, span)
                 out.extend(self._retire(span))
                 passes += 1
             self._g_occ.set(self.in_flight / self.S)
+            span.set(passes=passes)
         return out
 
     def drain(self) -> List[Completion]:

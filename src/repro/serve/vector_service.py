@@ -87,8 +87,7 @@ class ServiceStats:
 
     Each ``ServiceStats`` owns a private ``Registry`` by default (two
     services never share counts); pass one in to scrape several
-    services — or a service plus the device-telemetry bridge — from a
-    single exporter endpoint.
+    services from a single exporter endpoint.
     """
 
     def __init__(self, registry: Optional[Registry] = None):

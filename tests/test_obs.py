@@ -2,9 +2,9 @@
 exact-to-bucket percentiles and lossless merge, thread-safe counters,
 per-request trace spans threaded through the serving path (query ->
 per-shard probe -> merge -> epoch swap; failover / snapshot shipping),
-Prometheus + JSON exporters, the device-telemetry cost bridge — and
-the zero-cost-when-disabled contract (no span objects allocated on the
-untraced hot path; warmup batches never pollute the histograms)."""
+Prometheus + JSON exporters — and the zero-cost-when-disabled contract
+(no span objects allocated on the untraced hot path; warmup batches
+never pollute the histograms)."""
 import json
 import threading
 
@@ -13,8 +13,7 @@ import pytest
 
 from repro.obs import (NULL_SPAN, NULL_TRACER, Registry, Span, Tracer,
                        parse_prometheus, prometheus_families,
-                       record_search_stats, snapshot_json,
-                       to_prometheus)
+                       snapshot_json, to_prometheus)
 from repro.obs.metrics import DEFAULT, Histogram
 
 
@@ -178,6 +177,31 @@ def test_disabled_tracer_allocates_no_spans():
     assert Span.n_created == before
 
 
+def test_spans_on_the_monotonic_clock_and_in_the_profiler(tmp_path):
+    """Spans are stamped on ``time.monotonic``; those of an enabled
+    tracer (root and children) also land in a ``jax.profiler`` trace
+    under their own names, and a span made without a tracer does not."""
+    import time
+    import jax
+    from jax.profiler import ProfileData
+    tr = Tracer()
+    a = time.monotonic()
+    jax.profiler.start_trace(str(tmp_path))
+    with tr.span("obs.root") as root:
+        with root.child("obs.child") as kid:
+            kid.event("mark")
+    with Span("obs.loose"):
+        pass
+    jax.profiler.stop_trace()
+    b = time.monotonic()
+    assert a <= root.t0 <= kid.t0 <= kid.t1 <= root.t1 <= b
+    names = {ev.name for f in tmp_path.rglob("*.xplane.pb")
+             for plane in ProfileData.from_file(str(f)).planes
+             for line in plane.lines for ev in line.events}
+    assert {"obs.root", "obs.child"} <= names
+    assert "obs.loose" not in names
+
+
 # --------------------------------------------------------------------------
 # exporters
 # --------------------------------------------------------------------------
@@ -211,34 +235,6 @@ def test_prometheus_roundtrip_and_snapshot_stability():
     lat = next(f for f in snap["families"] if f["name"] == "lat_ms")
     assert lat["children"][0]["count"] == 4
     assert lat["children"][0]["p50"] > 0
-
-
-# --------------------------------------------------------------------------
-# the device-telemetry cost bridge
-# --------------------------------------------------------------------------
-
-def test_bridge_folds_telemetry_and_prices_queries():
-    from repro.configs.base import PHNSWConfig
-    from repro.obs.bridge import predicted_query_ns
-    cfg = PHNSWConfig()
-    reg = Registry()
-    stats = {"steps_total": np.full(32, 20.0),
-             "dist_h_evals": np.full(32, 60.0), "coverage": 1.0}
-    out = record_search_stats(stats, wall_s=0.004, registry=reg,
-                              cfg=cfg)
-    assert reg.histogram("phnsw_search_steps").count == 32
-    assert reg.histogram("phnsw_search_dist_h_evals").count == 32
-    assert reg.gauge("phnsw_search_coverage").value == 1.0
-    assert out["steps_mean"] == 20.0 and out["dist_h_mean"] == 60.0
-    assert out["measured_us"] == pytest.approx(125.0)
-    assert out["predicted_us"] > 0
-    assert out["cost_ratio"] == pytest.approx(
-        out["measured_us"] / out["predicted_us"])
-    assert reg.histogram("phnsw_cost_ratio").count == 1
-    # the prediction is monotone in the telemetry it prices
-    lo = predicted_query_ns(cfg, steps_mean=10, dist_h_mean=30)
-    hi = predicted_query_ns(cfg, steps_mean=40, dist_h_mean=120)
-    assert hi > lo > 0
 
 
 # --------------------------------------------------------------------------

@@ -211,3 +211,165 @@ def test_sharded_degraded_scheduler(small_dataset, small_pca):
     for c in comps:
         assert c.degraded and c.coverage < 1.0
         assert not ((c.ids >= lo) & (c.ids < hi)).any()
+
+
+# --------------------------------------------------------------------------
+# the tick's span tree and the exact Dist.H count
+# --------------------------------------------------------------------------
+
+PHASES = {"sched.admit", "sched.prepare", "sched.launch", "sched.wait",
+          "sched.rerank", "sched.complete"}
+
+
+def _serve_traced(svc, q, n_slots=16):
+    """Serve ``q`` as a trickle (a few arrivals per tick) through a
+    fresh scheduler with an enabled tracer; returns the scheduler, the
+    completions and the finished ``sched.tick`` roots."""
+    from repro.obs import Tracer
+    from repro.serve.scheduler import StreamScheduler
+    sched = StreamScheduler(svc, n_slots=n_slots)
+    sched.tracer = Tracer(capacity=None)
+    out = []
+    for i in range(len(q)):
+        sched.submit(q[i], k=10, rid=i)
+        if i % 5 == 4:
+            out.extend(sched.tick())
+    out.extend(sched.drain())
+    return sched, out, list(sched.tracer.finished)
+
+
+def test_tick_span_tree(stream_setup):
+    """An enabled tracer sees one ``sched.tick`` root per tick whose
+    children are the tick's phases: the admitted and retired counts
+    add up to the requests served, and the root's own time is under
+    its children's."""
+    svc, _, q, _ = stream_setup
+    sched, comps, roots = _serve_traced(svc, q[:80])
+    assert sorted(c.rid for c in comps) == list(range(80))
+    assert roots and all(r.name == "sched.tick" for r in roots)
+    for r in roots:
+        assert {c.name for c in r.iter_spans()} - {"sched.tick"} <= PHASES
+        assert r.children[0].name == "sched.admit"
+        assert {"passes"} <= set(r.attrs)
+        if r.find("sched.launch"):
+            assert {"width", "in_flight"} <= set(r.attrs)
+            assert r.find("sched.wait") is not None
+        for s in r.iter_spans():
+            assert s.t1 is not None and s.t0 <= s.t1
+    launches = [s for r in roots for s in r.find_all("sched.launch")]
+    assert {s.attrs["program"] for s in launches} <= {
+        "admit_step", "step", "step_prefix"}
+    assert "admit_step" in {s.attrs["program"] for s in launches}
+    assert all(s.attrs["width"] in sched.rungs for s in launches)
+    admits = [s for r in roots for s in r.find_all("sched.admit")]
+    assert sum(s.attrs["admitted"] for s in admits) == 80
+    assert all(s.attrs["queued_ms"] >= 0 and s.attrs["queued_max_ms"] >= 0
+               for s in admits)
+    done = [s for r in roots for s in r.find_all("sched.complete")]
+    assert sum(s.attrs["retired"] for s in done) == 80
+    assert sum(s.attrs["dist_h_rows"] for s in done) == \
+        sum(c.dist_h for c in comps)
+    total = sum(r.duration_ms for r in roots)
+    children = sum(c.duration_ms for r in roots for c in r.children)
+    assert total - children < children
+
+
+def _deferred_svc(small_dataset, small_graph, small_pca, kind):
+    import dataclasses
+    from repro.core.filters import PCAFilter, make_filter
+    from repro.core.search_jax import build_packed
+    from repro.serve.vector_service import VectorSearchService
+    x, _, _ = small_dataset
+    cfg = dataclasses.replace(small_graph.cfg, deferred_rerank=True,
+                              filter_kind=kind, pq_train_iters=8)
+    if kind == "pca":
+        filt = PCAFilter(small_pca, low_dtype=cfg.low_dtype)
+    else:
+        filt = make_filter(cfg, x, seed=0, pca=small_pca,
+                           levels=small_graph.levels)
+    g = dataclasses.replace(small_graph, cfg=cfg)
+    db = build_packed(g, filt.encode(x), filt=filt)
+    return VectorSearchService(db, filt=filt), db
+
+
+@pytest.mark.parametrize("kind", ["pca", "cascade"])
+def test_completion_dist_h_matches_sync(stream_setup, small_dataset,
+                                        small_graph, small_pca, kind):
+    """``Completion.dist_h`` is the synchronous path's exact
+    ``dist_h_evals`` for the same query: per-step Dist.H under the PCA
+    filter (escalations included), and the one deferred re-rank pass of
+    the cascade, whose ``sched.rerank`` span counts its rows."""
+    import jax.numpy as jnp
+    from repro.core.search_jax import search_batched
+    from repro.data.vectors import make_queries
+    if kind == "pca":
+        svc, db, q, _ = stream_setup
+        q = q[:96]
+    else:
+        svc, db = _deferred_svc(small_dataset, small_graph, small_pca,
+                                kind)
+        q = make_queries(small_dataset[0], 96, seed=7)
+    _, comps, roots = _serve_traced(svc, q)
+    qj = jnp.asarray(q)
+    _, fi, st = search_batched(db, qj, svc.filt.prepare_jnp(qj),
+                               ef0=svc.ef0, return_stats=True)
+    want = np.asarray(st["dist_h_evals"])
+    got = np.zeros(len(q), np.int64)
+    for c in comps:
+        got[c.rid] = c.dist_h
+        assert np.array_equal(c.ids, np.asarray(fi)[c.rid, :10])
+    assert (want > 0).all()
+    assert np.array_equal(got, want)
+    reranks = [s for r in roots for s in r.find_all("sched.rerank")]
+    if kind == "cascade":
+        assert reranks and all(s.find("sched.wait") for s in reranks)
+        assert sum(s.attrs["rows"] for s in reranks) == want.sum()
+    else:
+        assert not reranks
+
+
+def test_default_tracer_drain_allocates_no_spans(stream_setup):
+    """With the service's default (disabled) tracer a whole drain, tick
+    phases and escalations included, creates no ``Span``."""
+    from repro.obs import Span
+    svc, _, q, _ = stream_setup
+    sched = svc.scheduler()
+    for i in range(48):
+        sched.submit(q[i], k=10, rid=i)
+    before = Span.n_created
+    assert len(sched.drain()) == 48
+    assert Span.n_created == before
+
+
+def test_tick_spans_in_profiler_trace(stream_setup, tmp_path):
+    """Under an enabled tracer a tick's spans are also profiler
+    annotations: a ``jax.profiler`` trace of one tick holds
+    ``sched.tick`` on the host plane with its phases inside it."""
+    import jax
+    from jax.profiler import ProfileData
+    from repro.obs import Tracer
+    from repro.serve.scheduler import StreamScheduler
+    svc, _, q, _ = stream_setup
+    sched = StreamScheduler(svc, n_slots=16)
+    sched.tracer = Tracer()
+    for i in range(8):
+        sched.submit(q[i], k=10, rid=i)
+    jax.profiler.start_trace(str(tmp_path))
+    sched.tick()
+    jax.profiler.stop_trace()
+    ev = {}
+    for f in tmp_path.rglob("*.xplane.pb"):
+        for plane in ProfileData.from_file(str(f)).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for e in line.events:
+                        if e.name.startswith("sched."):
+                            ev.setdefault(e.name, []).append(
+                                (e.start_ns, e.start_ns + e.duration_ns))
+    assert {"sched.tick", "sched.admit", "sched.prepare",
+            "sched.launch", "sched.wait"} <= set(ev)
+    (t0, t1), = ev.pop("sched.tick")
+    assert all(t0 <= a <= b <= t1 for spans in ev.values()
+               for a, b in spans)
+    root = sched.tracer.last("sched.tick")
+    assert len(root.find_all("sched.wait")) == len(ev["sched.wait"])
