@@ -17,9 +17,12 @@ A ``FilterSpec`` owns (DESIGN.md § Filter-stage contract):
     in ``PackedDB.low`` and inlined per-neighbor in layout (3)
     (``PackedLayer.packed_low``) — dense f32/bf16 low-dim rows for PCA,
     uint8 codes for PQ, a zero-width array for identity;
-  * its **per-query preparation** (``prepare`` / ``prepare_jnp``): PCA
-    projection of the query vs. construction of the [S, 256] ADC
-    lookup table (identity needs none);
+  * its **per-query preparation**: PCA projection of the query vs.
+    construction of the [S, 256] ADC lookup table (identity needs
+    none). ``prepare_jnp`` is what serving runs: one jitted device
+    program per kind (``_prep``), the filter's constants
+    (``device_consts``) passed as arguments. ``prepare`` is the host
+    oracle, for ``search_ref``, the build and the mutable index;
   * its **device expand kernel** (``expand``): the fused
     Dist.L+mask+threshold+kSort.L kernel for PCA, the fused ADC kernel
     for PQ (the engine bypasses the kernel entirely for identity);
@@ -32,18 +35,34 @@ and batched engines share one filter definition per kind.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Optional
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import PHNSWConfig
-from repro.core.pca import PCA, fit_pca
+from repro.core.pca import PCA, fit_pca, project_jnp
 from repro.core.pq import (PQCodebook, adc_table_batch,
                            adc_tables_from_centroids, encode_pq,
                            train_pq)
 from repro.kernels import ops
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _prepare_jit(prep, consts, q):
+    """The device query prep of every filter kind. ``prep`` (a kind's
+    ``_prep``) is static, so a kind compiles once per batch width; the
+    constants are arguments, so a replaced filter of the same shapes
+    reuses the program and cannot serve stale constants."""
+    return prep(consts, q)
+
+
+def prepare_cache_size() -> int:
+    """Compiled-program count of the device query prep."""
+    return _prepare_jit._cache_size()
 
 
 class FilterSpec:
@@ -75,12 +94,24 @@ class FilterSpec:
 
     # --- per-query preparation ----------------------------------------------
     def prepare(self, q: np.ndarray) -> np.ndarray:
-        """q [B, D] -> host per-query filter data (f32)."""
+        """q [B, D] -> host per-query filter data (f32): the host
+        oracle of ``prepare_jnp``."""
+        raise NotImplementedError
+
+    def device_consts(self) -> tuple:
+        """The prep's constants as device arrays, uploaded once."""
+        return ()
+
+    @staticmethod
+    def _prep(consts, q):
+        """``prepare`` in jnp, from ``device_consts``."""
         raise NotImplementedError
 
     def prepare_jnp(self, q):
-        """Device-side ``prepare`` (jnp in, jnp out)."""
-        raise NotImplementedError
+        """Device-side ``prepare``, what serving runs: q [B, D] (device
+        or host) -> [B, ...] f32 on the device, one compiled program
+        per kind and batch width."""
+        return _prepare_jit(type(self)._prep, self.device_consts(), q)
 
     # --- host distance oracle (search_ref) ----------------------------------
     def dists(self, qprep_row: np.ndarray, payload: np.ndarray
@@ -122,8 +153,12 @@ class PCAFilter(FilterSpec):
     def prepare(self, q):
         return self.pca.transform(q).astype(np.float32)
 
-    def prepare_jnp(self, q):
-        return self.pca.transform_jnp(q).astype(jnp.float32)
+    def device_consts(self):
+        return self.pca.device_arrays()
+
+    @staticmethod
+    def _prep(consts, q):
+        return project_jnp(q.astype(jnp.float32), *consts)
 
     def dists(self, qprep_row, payload):
         d = payload.astype(np.float32) - qprep_row
@@ -161,11 +196,16 @@ class PQFilter(FilterSpec):
     def prepare(self, q):
         return adc_table_batch(self.cb, q)
 
-    def prepare_jnp(self, q):
-        # codebook uploaded once (same caching story as PCA.transform_jnp)
+    def device_consts(self):
+        # codebook uploaded once (same caching story as PCA.device_arrays)
         if self._cents_jnp is None:
             self._cents_jnp = jnp.asarray(self.cb.centroids)
-        return adc_tables_from_centroids(self._cents_jnp, q, jnp)
+        return (self._cents_jnp,)
+
+    @staticmethod
+    def _prep(consts, q):
+        cents, = consts
+        return adc_tables_from_centroids(cents, q, jnp)
 
     def dists(self, qprep_row, payload):
         S = qprep_row.shape[0]
@@ -240,11 +280,16 @@ class CascadeFilter(FilterSpec):
         qp = self.pca.transform(q).astype(np.float32)
         return np.concatenate([luts.reshape(len(q), -1), qp], axis=1)
 
-    def prepare_jnp(self, q):
+    def device_consts(self):
         if self._cents_jnp is None:
             self._cents_jnp = jnp.asarray(self.cb.centroids)
-        luts = adc_tables_from_centroids(self._cents_jnp, q, jnp)
-        qp = self.pca.transform_jnp(q).astype(jnp.float32)
+        return (self._cents_jnp,) + self.pca.device_arrays()
+
+    @staticmethod
+    def _prep(consts, q):
+        cents, mean, components = consts
+        luts = adc_tables_from_centroids(cents, q, jnp)
+        qp = project_jnp(q.astype(jnp.float32), mean, components)
         return jnp.concatenate([luts.reshape(q.shape[0], -1), qp],
                                axis=1)
 
@@ -296,7 +341,8 @@ class IdentityFilter(FilterSpec):
     def prepare(self, q):
         return q.astype(np.float32)[:, :0]     # [B, 0] — unused
 
-    def prepare_jnp(self, q):
+    @staticmethod
+    def _prep(consts, q):
         return q.astype(jnp.float32)[:, :0]
 
     def dists(self, qprep_row, payload):

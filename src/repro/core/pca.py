@@ -2,17 +2,27 @@
 from dim -> d_low, preserving maximum variance.
 
 Fit is exact (eigendecomposition of the covariance; numpy, done once at
-index-build time on the host). Transform is a jnp matmul so it can run
-sharded on the mesh. The transform keeps distances approximately:
-||P(x) - P(q)||^2 <= ||x - q||^2 (orthonormal rows), so low-dim distances
-underestimate true distances — the property the filter relies on."""
+index-build time on the host). ``transform`` is the host projection;
+``transform_jnp`` / ``project_jnp`` are its device twin, at f32. The
+transform keeps distances approximately: ||P(x) - P(q)||^2 <= ||x -
+q||^2 (orthonormal rows), so low-dim distances underestimate true
+distances — the property the filter relies on."""
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
+
+
+def project_jnp(x, mean, components):
+    """(x - mean) @ components on the device at f32: a TPU's default
+    matmul precision is one bf16 pass, so the projection asks for
+    ``Precision.HIGHEST``."""
+    return jnp.matmul(x - mean, components,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 @dataclass
@@ -20,7 +30,7 @@ class PCA:
     mean: np.ndarray        # [D]
     components: np.ndarray  # [D, d_low]  (orthonormal columns)
     explained: np.ndarray   # [d_low] fraction of variance per component
-    # device-array cache for transform_jnp: wrapping mean/components with
+    # device-array cache for device_arrays: wrapping mean/components with
     # jnp.asarray on every call re-pays a host->device transfer per
     # query batch; the projection matrices are frozen after fit, so they
     # are uploaded once and reused (excluded from ==/repr)
@@ -36,11 +46,15 @@ class PCA:
     def transform(self, x):
         return (x - self.mean) @ self.components
 
-    def transform_jnp(self, x):
+    def device_arrays(self):
+        """(mean, components) as device arrays, uploaded once."""
         if self._mean_jnp is None:
             self._mean_jnp = jnp.asarray(self.mean)
             self._components_jnp = jnp.asarray(self.components)
-        return (x - self._mean_jnp) @ self._components_jnp
+        return self._mean_jnp, self._components_jnp
+
+    def transform_jnp(self, x):
+        return project_jnp(x, *self.device_arrays())
 
     def inverse(self, z):
         return z @ self.components.T + self.mean

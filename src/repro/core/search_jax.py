@@ -44,6 +44,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import PHNSWConfig
 from repro.constants import INF as _INF, VALID_MAX
+from repro.core.filters import prepare_cache_size
 from repro.core.graph import HNSWGraph
 from repro.kernels import ops
 
@@ -1151,9 +1152,10 @@ def _retire_promote_jit(db, qprep, fi, n_keep):
 def slot_cache_sizes() -> Tuple[int, ...]:
     """(step, admit, step_sharded, admit_sharded, step_prefix,
     step_prefix_sharded, admit_step, admit_step_sharded,
-    retire_rerank, retire_promote) compiled-program cache sizes — the
-    scheduler's zero-recompile-under-churn assertions read these (same
-    pattern as ``core.distributed.search_cache_sizes``)."""
+    retire_rerank, retire_promote, the device query prep)
+    compiled-program cache sizes — the scheduler's
+    zero-recompile-under-churn assertions read these (same pattern as
+    ``core.distributed.search_cache_sizes``)."""
     return (_slot_step_jit._cache_size(),
             _slot_admit_jit._cache_size(),
             _slot_step_sharded_jit._cache_size(),
@@ -1163,4 +1165,5 @@ def slot_cache_sizes() -> Tuple[int, ...]:
             _slot_admit_step_jit._cache_size(),
             _slot_admit_step_sharded_jit._cache_size(),
             _retire_rerank_jit._cache_size(),
-            _retire_promote_jit._cache_size())
+            _retire_promote_jit._cache_size(),
+            prepare_cache_size())

@@ -195,24 +195,26 @@ class StreamScheduler:
         self._live_mask: Optional[np.ndarray] = None   # test override
         D = int(snap.high.shape[-1])
         self._D = D
-        qp_ex = svc.filt.prepare(np.zeros((1, D), np.float32))
-        dbv = self._db()
-        self.state = sj.make_slot_state(
-            dbv, self.S, np.asarray(qp_ex), ef=self.EFW,
-            n_shards=snap.n_shards if self.sharded else None,
-            deferred=self.deferred)
-        if self.sharded:
-            self._offsets = np.asarray(svc.sdb.offsets, np.int64)
         # WIDTH LADDER: slots are allocated low-first and each tick
         # runs the smallest compiled prefix covering the highest live
         # slot — a fixed set of widths, so partial occupancy neither
         # pays full-bank prices nor recompiles
         rungs = {self.S} | {w for w in range(16, self.S, 16)}
         self.rungs = sorted(rungs)
-        # warm every compiled program with a no-op admission (every
-        # pad row's slot id is out of range -> dropped) and an empty
-        # step (all budgets 0 -> the loop cond is false immediately);
-        # nothing is recorded, so service stats stay clean
+        qp_ex = svc.filt.prepare_jnp(
+            jnp.zeros((self.rungs[0], D), jnp.float32))
+        dbv = self._db()
+        self.state = sj.make_slot_state(
+            dbv, self.S, qp_ex, ef=self.EFW,
+            n_shards=snap.n_shards if self.sharded else None,
+            deferred=self.deferred)
+        if self.sharded:
+            self._offsets = np.asarray(svc.sdb.offsets, np.int64)
+        # warm every compiled program, the device query prep included,
+        # with a no-op admission (every pad row's slot id is out of
+        # range -> dropped) and an empty step (all budgets 0 -> the
+        # loop cond is false immediately); nothing is recorded, so
+        # service stats stay clean
         for wd in self.rungs:
             self.state = self._admit_step_call(
                 dbv, np.zeros((wd, D), np.float32),
@@ -255,25 +257,27 @@ class StreamScheduler:
 
     def _admit_step_call(self, dbv, q_new, slot_ids, ef_eff, budget,
                          width, span=NULL_SPAN):
-        with span.child("sched.prepare", rows=width):
-            qp = self.svc.filt.prepare(q_new)
         with span.child("sched.launch", program="admit_step",
-                        width=width):
-            self._push_budget()
-            args = (jnp.asarray(q_new), jnp.asarray(qp),
-                    jnp.asarray(slot_ids), jnp.asarray(ef_eff),
-                    jnp.asarray(budget))
+                        width=width) as ls:
+            nbytes = self._push_budget()
+            host = (q_new, slot_ids, ef_eff, budget)
+            qd, *rest = (jnp.asarray(a) for a in host)
+            # the filter's query prep runs on the device, from the raw
+            # queries just uploaded: no prepared table is uploaded
+            with ls.child("sched.prepare", rows=width):
+                qp = self.svc.filt.prepare_jnp(qd)
+            ls.set(bytes=nbytes + sum(a.nbytes for a in host))
             fn = sj._slot_admit_step_sharded_jit if self.sharded \
                 else sj._slot_admit_step_jit
-            return fn(dbv, self.state, *args, width, self.quantum,
-                      self.W, self.deferred)
+            return fn(dbv, self.state, qd, qp, *rest, width,
+                      self.quantum, self.W, self.deferred)
 
     def _step_call(self, dbv, width, span=NULL_SPAN):
         full = width >= self.S
         with span.child("sched.launch",
                         program="step" if full else "step_prefix",
-                        width=width):
-            self._push_budget()
+                        width=width) as ls:
+            ls.set(bytes=self._push_budget())
             if full:
                 fn = sj._slot_step_sharded_jit if self.sharded \
                     else sj._slot_step_jit
@@ -284,16 +288,17 @@ class StreamScheduler:
             return fn(dbv, self.state, width, self.quantum, self.W,
                       self.deferred)
 
-    def _push_budget(self) -> None:
+    def _push_budget(self) -> int:
         """Upload the host budgets an escalation changed, just before
-        the next compiled call reads them."""
+        the next compiled call reads them; returns the bytes uploaded."""
         if not self._budget_stale:
-            return
+            return 0
         self._budget_stale = False
         b = jnp.asarray(self._budget)
         if self.sharded:
             b = jnp.broadcast_to(b, self.state.budget.shape)
         self.state = _dc.replace(self.state, budget=b)
+        return self._budget.nbytes
 
     def _static_cap(self, ef_eff: int) -> int:
         """The per-request step bound — the exact bound the synchronous
@@ -535,8 +540,10 @@ class StreamScheduler:
 
         Traced, the ``sched.tick`` root's children cover the round:
         ``sched.admit`` (queue, sheds, slot bookkeeping, staging),
-        ``sched.prepare`` (the filter's host-side query prep),
-        ``sched.launch`` (uploads and the compiled call's dispatch),
+        ``sched.launch`` (uploads, whose ``bytes`` it counts, and the
+        compiled call's dispatch; an admitting launch holds
+        ``sched.prepare``, the dispatch of the filter's device query
+        prep over the uploaded rows),
         ``sched.wait`` (the host blocked on the device or its
         readback), ``sched.rerank`` (the deferred retirement passes)
         and ``sched.complete`` (the answers and their accounting); the

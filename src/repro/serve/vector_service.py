@@ -392,23 +392,23 @@ class VectorSearchService:
     def _run(self, q: np.ndarray, span=NULL_SPAN):
         if self.health is not None:
             return self._run_resilient(q, span=span)
-        qprep = self.filt.prepare(q)
+        # the scheduler's device query prep, so both paths score off
+        # bit-equal rows
+        qd = jnp.asarray(q)
+        qprep = self.filt.prepare_jnp(qd)
         if self.sdb is not None:
             if self.mesh is not None:
                 with span.child("search", path="mesh"):
-                    fd, fi = distributed_search(self.mesh, self.sdb,
-                                                jnp.asarray(q),
-                                                jnp.asarray(qprep),
-                                                ef0=self.ef0)
+                    fd, fi = distributed_search(self.mesh, self.sdb, qd,
+                                                qprep, ef0=self.ef0)
             else:
                 with span.child("search", path="host-sharded"):
-                    fd, fi = shard_search_host(self.sdb, jnp.asarray(q),
-                                               jnp.asarray(qprep),
+                    fd, fi = shard_search_host(self.sdb, qd, qprep,
                                                ef0=self.ef0)
         else:
             with span.child("search", path="single"):
-                fd, fi = search_batched(self.db, jnp.asarray(q),
-                                        jnp.asarray(qprep), ef0=self.ef0)
+                fd, fi = search_batched(self.db, qd, qprep,
+                                        ef0=self.ef0)
         return np.asarray(fd), np.asarray(fi)
 
     def _coverage(self, answered: np.ndarray) -> float:
@@ -435,7 +435,7 @@ class VectorSearchService:
         if plan is not None:
             plan.tick()
         qd = jnp.asarray(q)
-        qp = jnp.asarray(self.filt.prepare(q))
+        qp = self.filt.prepare_jnp(qd)
         ef0, _, deferred, rm, pm = _normalize(sdb, self.ef0, None, None,
                                               None)
         # per-shard list width: the cascade's promote pool when active

@@ -266,7 +266,7 @@ def test_cost_model_prices_cascade_two_stage(small_dataset, small_graph,
     assert "dist_m" not in c_p.breakdown
 
 
-@pytest.mark.parametrize("kind", ["pq", "cascade"])
+@pytest.mark.parametrize("kind", ["pca", "pq", "cascade"])
 def test_prepare_jnp_matches_host(small_dataset, filters, kind):
     """prepare_jnp (device path, shared ADC-table helper) reproduces
     the host prepare() tables/projections to float tolerance — the two
@@ -277,6 +277,43 @@ def test_prepare_jnp_matches_host(small_dataset, filters, kind):
     dev = np.asarray(filt.prepare_jnp(jnp.asarray(q[:8])))
     assert host.shape == dev.shape
     np.testing.assert_allclose(host, dev, atol=2e-3, rtol=1e-4)
+
+
+@pytest.mark.parametrize("kind", ["pca", "pq", "cascade"])
+def test_device_prep_rows_bit_equal_across_widths(small_dataset, filters,
+                                                  kind):
+    """The device prep gives each query the same row at every batch
+    width (the scheduler preps at its ladder widths, the synchronous
+    path at the service batch), and its PCA projection is f32: within
+    1e-5 of a float64 projection, relative to the largest coordinate."""
+    _, q, _ = small_dataset
+    filt = filters[kind]
+    q64 = np.asarray(q[:64], np.float32)
+    wide = np.asarray(filt.prepare_jnp(jnp.asarray(q64)))
+    narrow = np.asarray(filt.prepare_jnp(jnp.asarray(q64[:16])))
+    assert narrow.dtype == np.float32
+    assert np.array_equal(narrow, wide[:16])
+    if kind == "pq":
+        return
+    pca = filt.pca
+    ref = (q64.astype(np.float64) - pca.mean.astype(np.float64)) \
+        @ pca.components.astype(np.float64)
+    got = wide[:, wide.shape[1] - pca.d_low:]
+    assert np.abs(got - ref).max() <= 1e-5 * np.abs(ref).max()
+
+
+@pytest.mark.parametrize("kind", ["pca", "cascade"])
+def test_device_prep_projects_at_highest_precision(filters, kind):
+    """Every matmul of the device prep asks for HIGHEST precision: a
+    TPU's default is one bf16 pass, which the CPU cannot show by
+    value."""
+    from repro.core.filters import _prepare_jit
+    filt = filters[kind]
+    text = _prepare_jit.lower(type(filt)._prep, filt.device_consts(),
+                              jnp.zeros((16, 128), jnp.float32)).as_text()
+    dots = [ln for ln in text.splitlines() if "dot_general" in ln]
+    assert dots and all("precision = [HIGHEST, HIGHEST]" in ln
+                        for ln in dots)
 
 
 def test_train_pq_small_n_and_reseed():

@@ -328,6 +328,54 @@ def test_completion_dist_h_matches_sync(stream_setup, small_dataset,
         assert not reranks
 
 
+@pytest.fixture(scope="module")
+def cascade_svc(small_dataset, small_graph, small_pca):
+    return _deferred_svc(small_dataset, small_graph, small_pca,
+                         "cascade")[0]
+
+
+def test_admit_launch_uploads_raw_queries_only(cascade_svc, small_dataset):
+    """A cascade scheduler uploads the raw queries and its per-row
+    registers, never a prepared ``[width, n_sub*256 + d_low]`` table:
+    each admitting ``sched.launch`` carries ``bytes`` <= width * (D*4 +
+    64) and holds the ``sched.prepare`` of the device prep."""
+    from repro.data.vectors import make_queries
+    x = small_dataset[0]
+    D = x.shape[1]
+    q = make_queries(x, 60, seed=5)
+    sched, comps, roots = _serve_traced(cascade_svc, q)
+    assert sorted(c.rid for c in comps) == list(range(60))
+    launches = [s for r in roots for s in r.find_all("sched.launch")]
+    assert all(s.attrs["bytes"] >= 0 for s in launches)
+    admits = [s for s in launches if s.attrs["program"] == "admit_step"]
+    assert admits
+    for s in admits:
+        w = s.attrs["width"]
+        assert D * 4 * w <= s.attrs["bytes"] <= w * (D * 4 + 64)
+        prep, = s.find_all("sched.prepare")
+        assert prep.attrs["rows"] == w
+    assert all(s.find("sched.prepare") is None for s in launches
+               if s.attrs["program"] != "admit_step")
+
+
+def test_scheduler_never_calls_host_prepare(cascade_svc, small_dataset,
+                                            monkeypatch):
+    """The scheduler, from its construction to a drain, preps queries
+    on the device only: the filter's host ``prepare`` is never called."""
+    from repro.data.vectors import make_queries
+    from repro.serve.scheduler import StreamScheduler
+
+    def host_prepare(q):
+        raise AssertionError("the scheduler called the host prepare")
+
+    monkeypatch.setattr(cascade_svc.filt, "prepare", host_prepare)
+    sched = StreamScheduler(cascade_svc, n_slots=32)
+    q = make_queries(small_dataset[0], 40, seed=9)
+    for i in range(len(q)):
+        sched.submit(q[i], k=10, rid=i)
+    assert sorted(c.rid for c in sched.drain()) == list(range(40))
+
+
 def test_default_tracer_drain_allocates_no_spans(stream_setup):
     """With the service's default (disabled) tracer a whole drain, tick
     phases and escalations included, creates no ``Span``."""

@@ -135,3 +135,20 @@ def test_scheduler_tick_compiles(one_chip, compiled_path):
         s((S, DL), "float32"), s((S,), "int32"), s((S,), "int32"),
         s((S,), "int32"), width=S, quantum=32, expand_width=1)
     assert txt.count("tpu_custom_call") > 0
+
+
+def test_device_query_prep_compiles(one_chip):
+    """The scheduler's device query prep for the cascade (16 x 256 ADC
+    tables and the PCA 128->15 projection) at the full 64-slot width:
+    the projection's matmul keeps f32 on the chip (HIGHEST operand
+    precision, not one bf16 pass)."""
+    from repro.core.filters import CascadeFilter, _prepare_jit
+    s = lambda *a: _sds(*a, one_chip)
+    consts = (s((16, 256, 8), "float32"), s((128,), "float32"),
+              s((128, DL), "float32"))
+    txt = _compile_text(_prepare_jit, CascadeFilter._prep, consts,
+                        s((B, 128), "float32"))
+    dots = [ln for ln in txt.splitlines()
+            if "convolution(" in ln or " dot(" in ln]
+    assert dots and all("operand_precision={highest,highest}" in ln
+                        for ln in dots)
