@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """The control of the comparison that decides ``correct``: the plain
 reference computed one precision below what the deployment states
-(bfloat16 operands for its float32), put in the program's place and
+(bfloat16 operands for its float32), under the deployment's distance
+``metric``, put in the program's place and
 judged exactly as a run's answers are. It has to come out not correct.
 
     python3 bench/control.py --workload <cell> --seeds 1 2 3 --queries 70000
@@ -35,18 +36,19 @@ def control(cell_name: str, seed: int, n_queries: int, *,
     cell = spec.cell(cell_name)
     cfg = {**spec.config(cell["config"]), **(cfg_over or {})}
     mix = spec.mix(cell)
-    k = int(mix["k"])
+    k, metric = int(mix["k"]), cfg["metric"]
     x = deploy.make_data(cfg, seed)
     q = deploy.QueryPool(cfg, x, seed, 1)[np.arange(n_queries)]
-    gt = reference.exact_topk(x, q, 10)
-    ids = reference.exact_topk(x, q, k, operand_dtype="bfloat16")
-    dists = reference.bf16_dists(x, q, ids)
+    gt = reference.exact_topk(x, q, 10, metric=metric)
+    ids = reference.exact_topk(x, q, k, metric=metric,
+                               operand_dtype="bfloat16")
+    dists = reference.bf16_dists(x, q, ids, metric=metric)
     # the control's own distances pick its order, as a program's do
     o = np.argsort(dists, axis=1, kind="stable")
     ids = np.take_along_axis(ids, o, axis=1)
     dists = np.take_along_axis(dists, o, axis=1)
     return reference.judge(x, q, ids, dists, gt, unanswered=0,
-                           limits=cfg, k=k)
+                           limits=cfg, metric=metric, k=k)
 
 
 def main(argv=None) -> None:

@@ -1,4 +1,5 @@
-"""Set a deployment up from its file: data and queries from the seed,
+"""Set a deployment up from its file: data and queries from the seed
+(through the generator that ``cfg["data"]`` names under ``datasets/``),
 the index through the program's own build path (the module that
 ``cfg["index"]`` names under ``indexes/``), and the service's
 continuous-batching scheduler.
@@ -11,7 +12,7 @@ from typing import Dict
 
 import numpy as np
 
-from bench import data, spec
+from bench import spec
 
 
 def program_config(cfg: dict):
@@ -24,18 +25,15 @@ def program_config(cfg: dict):
     return PHNSWConfig(**kw)
 
 
-def make_data(cfg: dict, seed: int) -> np.ndarray:
-    return data.make_sift_like(
-        int(cfg["n_points"]), int(cfg["dim"]),
-        n_clusters=int(cfg["n_clusters"]), intrinsic=int(cfg["intrinsic"]),
-        noise=float(cfg["noise"]), seed=seed)
+def make_data(cfg: dict, seed: int, bench: Path = spec.BENCH
+              ) -> np.ndarray:
+    return spec.dataset(cfg, bench).make(cfg, seed)
 
 
 def make_queries(cfg: dict, x: np.ndarray, n: int, seed: int,
-                 stream: int) -> np.ndarray:
+                 stream: int, bench: Path = spec.BENCH) -> np.ndarray:
     """``n`` queries of stream ``stream`` for run seed ``seed``."""
-    return data.make_queries(x, n, seed=[seed, stream],
-                             jitter=float(cfg["query_jitter"]))
+    return spec.dataset(cfg, bench).queries(cfg, x, n, [seed, stream])
 
 
 class QueryPool:
@@ -47,15 +45,16 @@ class QueryPool:
     BLOCK = 4096
 
     def __init__(self, cfg: dict, x: np.ndarray, seed: int,
-                 stream: int):
+                 stream: int, bench: Path = spec.BENCH):
         self.cfg, self.x, self.seed, self.stream = cfg, x, seed, stream
+        self._queries = spec.dataset(cfg, bench).queries
         self._blocks: Dict[int, np.ndarray] = {}
 
     def _block(self, b: int) -> np.ndarray:
         if b not in self._blocks:
-            self._blocks[b] = data.make_queries(
-                self.x, self.BLOCK, seed=[self.seed, self.stream, b],
-                jitter=float(self.cfg["query_jitter"]))
+            self._blocks[b] = self._queries(
+                self.cfg, self.x, self.BLOCK,
+                [self.seed, self.stream, b])
         return self._blocks[b]
 
     def fill(self, n: int) -> None:

@@ -10,7 +10,8 @@ index through the program's build path, brings up the service and its
 scheduler, warms every program the window runs, and then drives the mix
 for ``--seconds``. Everything before the window is ``setup_s``. After
 the window the service is freed and every answer is compared with the
-exact reference (``reference.py``); each compared number and its limit
+exact reference (``reference.py``) under the deployment's distance
+``metric``; each compared number and its limit
 are printed as the last lines of standard error and under ``checks``
 in the result.
 
@@ -146,8 +147,9 @@ def measure(cell_name: str, seed: int, seconds: float, trace: bool, *,
     n_warm = warmup_queries if warmup_queries is not None \
         else WARM_PER_SLOT * int(cfg["n_slots"])
 
-    x = deploy.make_data(cfg, seed)
-    pool = deploy.QueryPool(cfg, x, seed, 1)
+    metric = cfg["metric"]
+    x = deploy.make_data(cfg, seed, bench)
+    pool = deploy.QueryPool(cfg, x, seed, 1, bench)
     plan = loop.plan(mix, seconds, seed, pool)
     t = time.perf_counter()
     svc = deploy.service(cfg, x, seed, bench)
@@ -155,7 +157,7 @@ def measure(cell_name: str, seed: int, seconds: float, trace: bool, *,
     t = time.perf_counter()
     sched = deploy.scheduler(svc, cfg)
     warm_missing = drive.warm_up(
-        sched, deploy.make_queries(cfg, x, n_warm, seed, 2), k,
+        sched, deploy.make_queries(cfg, x, n_warm, seed, 2, bench), k,
         limit_s=drain_s)
     log(f"scheduler and warm-up: {time.perf_counter() - t:.1f} s"
         + (f"; {warm_missing} warm-up queries never came back"
@@ -243,11 +245,11 @@ def measure(cell_name: str, seed: int, seconds: float, trace: bool, *,
         dists[row, :len(a_dists)] = a_dists[:k]
     t = time.perf_counter()
     q = pool[rids]
-    gt = reference.exact_topk(x, q, 10) if len(rids) else \
-        np.zeros((0, 10), np.int64)
+    gt = reference.exact_topk(x, q, 10, metric=metric) if len(rids) \
+        else np.zeros((0, 10), np.int64)
     verdict = reference.judge(x, q, ids, dists, gt,
                               unanswered=win.unanswered + warm_missing,
-                              limits=cfg, k=k)
+                              limits=cfg, metric=metric, k=k)
     log(f"reference and comparison: {time.perf_counter() - t:.1f} s "
         f"over {len(rids)} answers")
 

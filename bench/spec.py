@@ -6,7 +6,11 @@ and in the files those name:
 * ``configs/<config>.json``: one deployment (its source, shapes, index
   setup, cuts, assumed sizes and the guarantees the comparison holds
   it to); its ``index`` names the module that builds it,
-  ``indexes/<index>.py``;
+  ``indexes/<index>.py``; its ``data`` names the generator of its
+  vectors and queries, ``datasets/<data>.py``, with
+  ``make(cfg, seed) -> x`` and ``queries(cfg, x, n, seed_seq) -> q``;
+  its ``metric`` is the distance the reference holds the answers to,
+  one of ``METRICS``. Both keys are required;
 * ``traffic/<mix>.json``: one traffic mix's parameters; its ``loop``
   names the generator that drives it, ``loops/<loop>.py``;
 * ``end_to_end/<metric>.py`` and ``layer_metrics/<metric>.py``: the
@@ -14,8 +18,9 @@ and in the files those name:
   metric ``<name>.<suffix>`` without a file of its own is read by
   ``<name>.py``.
 
-Adding a cell, a deployment, a mix, a loop, an index kind or a metric
-adds files and ``BENCHMARK.json`` entries; nothing here changes.
+Adding a cell, a deployment, a mix, a loop, an index kind, a data
+generator or a metric adds files and ``BENCHMARK.json`` entries;
+nothing here changes.
 """
 from __future__ import annotations
 
@@ -25,6 +30,11 @@ from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+
+# the distances a deployment may state: squared L2; hnswlib's "cosine"
+# space, 1 - q.x / (|q| |x|) (ann-benchmarks' angular distance); and
+# hnswlib's "ip" space, 1 - q.x
+METRICS = ("l2", "cosine", "ip")
 
 
 def _json(path: Path) -> dict:
@@ -44,7 +54,16 @@ def cell(name: str, bench: Path = BENCH) -> dict:
 
 
 def config(name: str, bench: Path = BENCH) -> dict:
-    return _json(bench / "configs" / f"{name}.json")
+    """The deployment's file; it has to state ``data`` and a ``metric``
+    of ``METRICS``."""
+    cfg = _json(bench / "configs" / f"{name}.json")
+    for key in ("data", "metric"):
+        if key not in cfg:
+            raise ValueError(f"configs/{name}.json states no {key!r}")
+    if cfg["metric"] not in METRICS:
+        raise ValueError(f"configs/{name}.json: metric "
+                         f"{cfg['metric']!r} is not one of {METRICS}")
+    return cfg
 
 
 def mix(c: dict, bench: Path = BENCH) -> dict:
@@ -83,6 +102,12 @@ def reader(metric_name: str, bench: Path = BENCH,
 def loop(mix: dict, bench: Path = BENCH):
     """The generator module of a traffic mix (``loops/<loop>.py``)."""
     return module("loops", mix["loop"], bench)
+
+
+def dataset(cfg: dict, bench: Path = BENCH):
+    """The generator of a deployment's vectors and queries
+    (``datasets/<data>.py``)."""
+    return module("datasets", cfg["data"], bench)
 
 
 def index(cfg: dict, bench: Path = BENCH):

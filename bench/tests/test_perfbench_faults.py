@@ -28,6 +28,18 @@ def test_control_is_not_correct(cell):
     assert c["dist_gap"]["value"] > c["dist_gap"]["limit"]
 
 
+@pytest.mark.parametrize("metric", ["l2", "cosine", "ip"])
+def test_control_is_not_correct_under_each_metric(metric):
+    """The control under the metric a deployment states: the gap of its
+    bfloat16 distances to the float64 ones fails ``dist_gap``."""
+    res = control.control("sift250k-pca.open", 2**31 + 5, 200,
+                          cfg_over={"n_points": 3000, "metric": metric})
+    assert not res["correct"]
+    c = res["checks"]
+    assert c["dist_gap"]["value"] > c["dist_gap"]["limit"]
+    assert c["unanswered"]["value"] == 0 and c["bad_rows"]["value"] == 0
+
+
 def _frozen_step(db, state, **kw):
     return state
 

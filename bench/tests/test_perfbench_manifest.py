@@ -168,16 +168,43 @@ def service(cfg, x, seed, pc):
 """
 
 
-def test_cell_of_files_only(tmp_path, man):
-    """A new cell on a new deployment (its own index module) under a new
-    mix (its own loop module), with a new per-layer and a new
+_DATA = """
+import numpy as np
+
+
+def make(cfg, seed):
+    # signed Gaussian blobs around a low-rank manifold
+    rng = np.random.default_rng([seed, 7])
+    n, d = int(cfg["n_points"]), int(cfg["dim"])
+    centers = 4.0 * rng.standard_normal((32, d))
+    basis = rng.standard_normal((8, d))
+    x = centers[rng.integers(0, 32, n)] \\
+        + 0.5 * rng.standard_normal((n, 8)) @ basis \\
+        + 0.05 * rng.standard_normal((n, d))
+    return x.astype(np.float32)
+
+
+def queries(cfg, x, n, seed_seq):
+    rng = np.random.default_rng(seed_seq)
+    q = x[rng.integers(0, len(x), n)] \\
+        + 0.05 * rng.standard_normal((n, x.shape[1]))
+    return q.astype(np.float32)
+"""
+
+
+def _files_only(tmp_path, man, metric):
+    """A copy of the benchmark with a new cell on a new deployment (its
+    own data generator and index module, stating ``metric``) under a
+    new mix (its own loop module), with a new per-layer and a new
     end-to-end metric, added as files and manifest entries alone."""
     bench = tmp_path / "bench"
     shutil.copytree(ROOT / "bench", bench, ignore=shutil.ignore_patterns(
         "out", "tests", "__pycache__"))
     cfg = json.loads((bench / "configs" / "sift250k-pca.json").read_text())
-    cfg.update(name="files-only", index="mutable_again")
+    cfg.update(name="files-only", index="mutable_again", data="blobs",
+               metric=metric)
     (bench / "configs" / "files-only.json").write_text(json.dumps(cfg))
+    (bench / "datasets" / "blobs.py").write_text(_DATA)
     (bench / "indexes" / "mutable_again.py").write_text(_INDEX)
     (bench / "loops" / "even.py").write_text(_EVEN_LOOP)
     (bench / "traffic" / "even.json").write_text(json.dumps(
@@ -206,6 +233,14 @@ def test_cell_of_files_only(tmp_path, man):
         "better": "higher", "source": "host_clock", "layer": "serve",
         "moves": "answered", "workloads": [name]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(man))
+    return bench, name
+
+
+def test_cell_of_files_only(tmp_path, man):
+    """A new cell on a new deployment with a data generator of its own,
+    under a new mix, with new metrics, added as files and manifest
+    entries alone, runs and is correct."""
+    bench, name = _files_only(tmp_path, man, "l2")
     for trace in (False, True):
         res = run.measure(name, 3, SECONDS, trace,
                           t_start=time.perf_counter(), cfg_over=CFG,
@@ -217,3 +252,43 @@ def test_cell_of_files_only(tmp_path, man):
         else:
             assert got["answered"]["value"] == 40
             assert {"setup_s", "recall_at_10"} <= set(got)
+
+
+def test_cell_of_files_only_is_held_to_its_metric(tmp_path, man):
+    """The same files stating ``"metric": "cosine"``, served by the
+    program's squared-L2 path: the comparison holds the answers to the
+    stated metric, and the returned distances fail ``dist_gap``."""
+    bench, name = _files_only(tmp_path, man, "cosine")
+    res = run.measure(name, 3, SECONDS, False,
+                      t_start=time.perf_counter(), cfg_over=CFG,
+                      bench=bench, **RUN)
+    c = res["checks"]
+    assert not res["correct"], c
+    assert c["dist_gap"]["value"] > c["dist_gap"]["limit"]
+    assert c["unanswered"]["value"] == 0 and c["bad_rows"]["value"] == 0
+
+
+def test_configs_state_data_and_metric(man):
+    """Every deployment names its data generator, whose module exists
+    with ``make`` and ``queries``, and one of the three metrics."""
+    for c in man["configs"]:
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        assert cfg["metric"] in spec.METRICS, c["name"]
+        assert NAME.match(cfg["data"])
+        assert (ROOT / "bench" / "datasets" / f"{cfg['data']}.py").is_file()
+        mod = spec.dataset(cfg)
+        assert callable(mod.make) and callable(mod.queries)
+
+
+@pytest.mark.parametrize("change", ["no_data", "no_metric", "bad_metric"])
+def test_config_that_does_not_state_data_and_metric_is_refused(
+        tmp_path, change):
+    (tmp_path / "configs").mkdir()
+    cfg = spec.config("sift250k-pca")
+    if change == "bad_metric":
+        cfg["metric"] = "angular"
+    else:
+        del cfg[change[len("no_"):]]
+    (tmp_path / "configs" / "x.json").write_text(json.dumps(cfg))
+    with pytest.raises(ValueError):
+        spec.config("x", tmp_path)

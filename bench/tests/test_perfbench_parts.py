@@ -93,7 +93,8 @@ def test_load_recorded_trace(tmp_path):
 
 def test_work_hand_computed():
     pca = {"filter_kind": "pca", "M0": 32, "dim": 128, "d_low": 15,
-           "k_schedule": [16, 8, 3], "deferred_rerank": False}
+           "k_schedule": [16, 8, 3], "deferred_rerank": False,
+           "metric": "l2"}
     w = work.role_work(pca, [10, 20])                 # 30 steps, 2 queries
     assert w["filter"] == (3 * 32 * 15 * 30, 4 * (32 * 15 + 15) * 30)
     assert w["filter"][1] == 59_400
@@ -102,7 +103,7 @@ def test_work_hand_computed():
     assert w["dist_h"][1] == 261_120
     cas = {"filter_kind": "cascade", "M0": 32, "dim": 128,
            "pq_n_sub": 16, "deferred_rerank": True, "rerank_mult": 3,
-           "ef0": 10, "k_schedule": [16, 8, 3]}
+           "ef0": 10, "k_schedule": [16, 8, 3], "metric": "l2"}
     w = work.role_work(cas, [10, 20])
     assert w["filter"] == (32 * 16 * 30, (32 * 16 + 4 * 32 * 16) * 30)
     assert w["dist_h"] == (3 * 30 * 2 * 128, 4 * 30 * 2 * 128 + 4 * 128 * 2)
@@ -111,6 +112,20 @@ def test_work_hand_computed():
     assert bound == "bytes" and t == pytest.approx(1.0)
     t, bound = work.least_seconds(197e12, 1.0, p)
     assert bound == "operations" and t == pytest.approx(1.0)
+
+
+@pytest.mark.parametrize("metric", ["cosine", "ip"])
+def test_work_dist_h_by_metric(metric):
+    """Under cosine and ip a Dist.H row is a dot product: 2 operations
+    per element where l2 takes 3; the bytes and the filter's count do
+    not change."""
+    cfg = {"filter_kind": "pca", "M0": 32, "dim": 100, "d_low": 15,
+           "k_schedule": [16, 8, 3], "deferred_rerank": False}
+    l2 = work.role_work({**cfg, "metric": "l2"}, [10, 20])
+    w = work.role_work({**cfg, "metric": metric}, [10, 20])
+    assert w["dist_h"] == (2 * 16 * 30 * 100, l2["dist_h"][1])
+    assert l2["dist_h"][0] == 3 * 16 * 30 * 100
+    assert w["filter"] == l2["filter"]
 
 
 def test_unknown_device_is_an_error():

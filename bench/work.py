@@ -10,11 +10,14 @@ The count is of the work the algorithm needs, whatever kernel does it:
   (pq, cascade): S uint8 codes per row and the S table entries they
   select, 4 bytes each, one add per code.
 * ``dist_h``, the full-dimension re-rank: D float32 per row re-ranked
-  plus the query, 3 operations per element. Per-step mode re-ranks at
-  most ``k_schedule[0]`` survivors per layer-0 step; deferred mode
-  re-ranks ``rerank_mult * ef0`` rows once per query. The slotted path
-  exposes no exact count of Dist.H rows, so these bounds stand in: they
-  count a step's whole survivor list, also where fewer rows survive.
+  plus the query; 3 operations per element under the ``l2`` metric
+  (subtract, multiply, add), 2 under ``cosine`` and ``ip`` (multiply,
+  add: rows held unit-norm need only a dot product). Per-step mode
+  re-ranks at most ``k_schedule[0]`` survivors per layer-0 step;
+  deferred mode re-ranks ``rerank_mult * ef0`` rows once per query. The
+  slotted path exposes no exact count of Dist.H rows, so these bounds
+  stand in: they count a step's whole survivor list, also where fewer
+  rows survive.
 
 Two errors pull a share apart: upper-layer descent work is not counted
 while its kernel time is (the share errs low), and the Dist.H row count
@@ -24,6 +27,9 @@ for; PERF.md says which is larger where a trace shows it.
 from __future__ import annotations
 
 import numpy as np
+
+# operations per element of a Dist.H row, by distance metric
+DIST_H_OPS = {"l2": 3, "cosine": 2, "ip": 2}
 
 
 def role_work(cfg: dict, steps) -> dict:
@@ -47,7 +53,8 @@ def role_work(cfg: dict, steps) -> dict:
     else:
         rows = int(cfg["k_schedule"][0]) * n_steps
         q_reads = n_steps
-    out["dist_h"] = (3 * rows * D, 4 * rows * D + 4 * D * q_reads)
+    per_elem = DIST_H_OPS[cfg["metric"]]
+    out["dist_h"] = (per_elem * rows * D, 4 * rows * D + 4 * D * q_reads)
     return out
 
 
